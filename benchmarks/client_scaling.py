@@ -9,6 +9,8 @@ backend-scaling chart: per-round wall clock vs n_clients for the vmapped
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import BenchSettings, csv, run_method
 
 
@@ -109,6 +111,7 @@ def main():
     ap.add_argument("--scaling-only", action="store_true",
                     help="skip the Table-5 accuracy sweep")
     args = ap.parse_args()
+    enable_compile_cache()
 
     backends = (("vmapped", "sharded") if args.backend == "both"
                 else (args.backend,))

@@ -46,6 +46,7 @@ from repro.core import glasu
 from repro.fed import simulation
 from repro.graph.sampler import GlasuSampler
 from repro.graph.synth import make_vfl_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 HOT = dict(dataset="cora", n_clients=3, n_layers=4, hidden=64,
            backbone="gcnii", batch_size=16, fanout=3, size_cap=512)
@@ -168,6 +169,7 @@ def main():
     ap.add_argument("--out", default="BENCH_comm.json")
     ap.add_argument("--rounds", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     run(smoke=args.smoke, out_path=args.out, rounds=args.rounds)
 
 

@@ -9,6 +9,8 @@ import argparse
 import os
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -16,6 +18,7 @@ def main() -> None:
     ap.add_argument("--skip-gnn", action="store_true",
                     help="only kernels + roofline (no GNN training)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from . import kernel_bench, roofline
     print("# kernels")

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.api import ExperimentConfig, Trainer
 from repro.comm.compression import make_compressor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import InferenceSession, ServeConfig
 
 HOT = dict(dataset="cora", n_clients=3, n_layers=4, hidden=64,
@@ -214,6 +215,7 @@ def main():
     ap.add_argument("--out", default="BENCH_serve.json")
     ap.add_argument("--rounds", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     run(smoke=args.smoke, out_path=args.out, rounds=args.rounds)
 
 

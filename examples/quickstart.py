@@ -29,9 +29,11 @@ Knobs demonstrated below:
     ``backend="sharded"`` places each client on its own device.
 """
 from repro.api import Trainer, get_preset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = get_preset("cora-gcnii-glasu").with_(
         rounds=60, eval_every=20, rounds_per_step=4,
         compression={"method": "int8"})

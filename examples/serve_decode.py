@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--window", type=int, default=0,
                     help=">0: sliding-window ring cache")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced("smollm_360m")
     if args.window:

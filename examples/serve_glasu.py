@@ -23,10 +23,12 @@ import tempfile
 import numpy as np
 
 from repro.api import Trainer, get_preset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import InferenceSession, MicroBatcher, ServeConfig
 
 
 def main():
+    enable_compile_cache()
     ckpt_dir = tempfile.mkdtemp(prefix="glasu-serve-")
     cfg = get_preset("cora-gcnii-glasu").with_(
         rounds=30, eval_every=30, ckpt_dir=ckpt_dir)

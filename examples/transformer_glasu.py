@@ -16,6 +16,7 @@ import jax
 from repro.configs.base import ArchConfig, GlasuSplit
 from repro.core.steps import make_train_step
 from repro.data.pipeline import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ArchConfig(
         name="glasu-tp-20m", kind="dense",

@@ -13,6 +13,7 @@ compressed rows show bytes-per-round dropping with accuracy held.
 import argparse
 
 from repro.api import ExperimentConfig, Trainer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(label, cfg):
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--backend", default="vmapped",
                     choices=("vmapped", "simulation", "sharded"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = ExperimentConfig(
         name=f"{args.dataset}-comparison", dataset=args.dataset,

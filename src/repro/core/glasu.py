@@ -1145,13 +1145,12 @@ def make_sharded_round_fn(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
     the stale-cache carry there and appends the round's fault masks —
     composed configs thread both: ``(params, opt_state, comp_state,
     fault_state, batch, key, faults)``."""
-    from jax.experimental.shard_map import shard_map
-
     m_loc = _client_axis_check(cfg, mesh, axis)
     pol = _policy(cfg, axis_name=axis, m_loc=m_loc, record=record)
     in_specs, out_specs = _round_specs(cfg, optimizer, pol, axis)
-    fn = shard_map(_round_caller(cfg, optimizer, pol), mesh=mesh,
-                   in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(_round_caller(cfg, optimizer, pol), mesh=mesh,
+                       in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(fn) if jit else fn
 
 
@@ -1164,16 +1163,15 @@ def make_sharded_multi_round_fn(cfg: GlasuConfig,
     carry-layout and round-stacked batch contract as
     ``make_multi_round_fn`` (the (K, M) fault-mask stacks ride the scan
     xs, replicated across devices)."""
-    from jax.experimental.shard_map import shard_map
-
     m_loc = _client_axis_check(cfg, mesh, axis)
     pol = _policy(cfg, axis_name=axis, m_loc=m_loc)
     has_c, has_f = _policy_arity(pol)
     in_specs, out_specs = _round_specs(cfg, optimizer, pol, axis,
                                        round_stacked=True)
     step_fn = jax.jit(
-        shard_map(_multi_round_caller(cfg, optimizer, pol), mesh=mesh,
-                  in_specs=in_specs, out_specs=out_specs, check_rep=False),
+        jax.shard_map(_multi_round_caller(cfg, optimizer, pol), mesh=mesh,
+                      in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False),
         donate_argnums=tuple(range(2 + has_c + has_f)))
     if rounds_per_step is None:
         return step_fn
@@ -1183,7 +1181,6 @@ def make_sharded_multi_round_fn(cfg: GlasuConfig,
 def make_sharded_joint_fn(cfg: GlasuConfig, mesh, axis: str = "clients"):
     """JointInference logits with clients sharded over the mesh: returns the
     global (M, S, C) stack (assembled from per-device blocks)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m_loc = _client_axis_check(cfg, mesh, axis)
@@ -1195,9 +1192,9 @@ def make_sharded_joint_fn(cfg: GlasuConfig, mesh, axis: str = "clients"):
                                             axis_name=axis, m_loc=m_loc)
         return logits
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(pspecs, bspecs, P()),
-                             out_specs=P(axis), check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(pspecs, bspecs, P()),
+                                 out_specs=P(axis), check_vma=False))
 
 
 # ---------------------------------------------------------------- evaluation
@@ -1362,7 +1359,6 @@ def make_sharded_serve_fn(cfg: GlasuConfig, mesh, axis: str = "clients",
     output reassembled to the global (M, ...) stack; ``inject`` is the
     replicated ``{l: (keep, rows)}`` cache-injection dict (every
     aggregation layer present)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m_loc = _client_axis_check(cfg, mesh, axis)
@@ -1376,9 +1372,9 @@ def make_sharded_serve_fn(cfg: GlasuConfig, mesh, axis: str = "clients",
                                      cache_inject=inject)
 
     out_specs = (P(axis), {l: P(axis) for l in cfg.agg_layers})
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(pspecs, bspecs, ispecs),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(pspecs, bspecs, ispecs),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def accuracy_from_logits(logits, labels, idx, mode: str = "ensemble"):

@@ -70,7 +70,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B, S, H, dh); k/v: (B, T, Kv, dh) -> (B, S, H, dh)."""
     b, s, h, dh = q.shape
     t, kv = k.shape[1], k.shape[2]

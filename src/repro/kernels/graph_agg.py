@@ -27,8 +27,13 @@ usual hidden sizes (d_out <= 128) there is exactly one d_out tile and nothing
 is recomputed.
 
 Per-tile VMEM: scatter matrix (128 x n_src) + source rows (n_src x d) +
-one weight tile (d x DOUT_BLOCK) — with the sampler's n_src <= size_cap (512)
-and d <= 512 this stays well under the ~16 MB v5e budget.
+one weight tile (d x DOUT_BLOCK). With the sampler's n_src <= size_cap (512)
+and d <= 512 the kernel compiles for v5e (tests/test_tpu_compile.py); the
+v5e compiler runs out of VMEM at n_src = 8192 with d = 512, so full-graph
+sweeps at that scale need narrow rows or the CSR path below.
+
+Every entry point takes ``interpret`` without a default: ``kernels.ops``
+decides it from the backend, and a direct caller must say which it wants.
 """
 from __future__ import annotations
 
@@ -41,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DST_BLOCK = 128
 DOUT_BLOCK = 128
+LANE = 128
 NEG_INF = -1e9
 
 
@@ -59,9 +65,9 @@ def _scatter_matrix(idx, mask, n_src):
 
 
 def _select_matrix(idx_col, n_src):
-    """Unmasked one-hot row-select matrix for a single index column."""
+    """Unmasked one-hot row-select matrix for one (n, 1) index column."""
     src = jax.lax.broadcasted_iota(jnp.int32, (idx_col.shape[0], n_src), 1)
-    return jnp.where(idx_col[:, None] == src, 1.0, 0.0)
+    return jnp.where(idx_col == src, 1.0, 0.0)
 
 
 def _masked_mean(idx_ref, mask_ref, h_ref):
@@ -100,7 +106,7 @@ def _graph_agg_kernel(idx_ref, mask_ref, h_ref, w_ref, out_ref):
                            ).astype(out_ref.dtype)
 
 
-def graph_agg_pallas(h, idx, mask, w, *, interpret: bool = True):
+def graph_agg_pallas(h, idx, mask, w, *, interpret: bool):
     """h: (n_src, d), idx/mask: (n_dst, F), w: (d, d_out) -> (n_dst, d_out)."""
     n_dst, fanout = idx.shape
     d = h.shape[1]
@@ -143,9 +149,10 @@ def graph_agg_pallas(h, idx, mask, w, *, interpret: bool = True):
 # against the LOCAL destination row of each edge (sentinel 128 = padding,
 # matches no row), making the kernel grid-position-free: no program_id, no
 # SMEM scalars, safe under the core's client-axis vmap exactly like the
-# dense kernels. The source-row gather is a vector ``jnp.take`` — the one
-# TPU-adaptation point (lowers via Mosaic dynamic-gather; interpret mode on
-# CPU executes it as XLA gather).
+# dense kernels. The source-row gather runs in XLA before the pallas_call
+# (Mosaic has no vector gather over a VMEM-resident row block), so each
+# program reads only its own tile's (slab, d) gathered rows — never the
+# whole (n_src, d) source matrix, which at graph scale would not fit VMEM.
 
 CSR_PAD_ROW = DST_BLOCK          # local-seg sentinel: matches no tile row
 
@@ -174,15 +181,13 @@ def ell_to_slabs(idx, mask):
     return idx_slab, seg_slab, ew_slab, n_dst
 
 
-def _csr_agg_kernel(idx_ref, seg_ref, ew_ref, h_ref, w_ref, out_ref):
+def _csr_agg_kernel(seg_ref, ew_ref, g_ref, w_ref, out_ref):
     """One (dst tile, d_out tile) program over the tile's edge slab."""
-    seg = jnp.transpose(seg_ref[...])                   # (1, slab) local row
-    ew = jnp.transpose(ew_ref[...]).astype(jnp.float32)
+    seg = seg_ref[...]                                  # (1, slab) local row
+    ew = ew_ref[...].astype(jnp.float32)
     rows = jax.lax.broadcasted_iota(jnp.int32, (DST_BLOCK, seg.shape[1]), 0)
     a = jnp.where(rows == seg, ew, 0.0)                 # (128, slab)
-    gathered = jnp.take(h_ref[...].astype(jnp.float32), idx_ref[...][:, 0],
-                        axis=0)                         # (slab, d)
-    s = jnp.dot(a, gathered, preferred_element_type=jnp.float32)
+    s = jnp.dot(a, g_ref[...], preferred_element_type=jnp.float32)
     denom = jnp.maximum(jnp.sum(a, axis=1, keepdims=True), 1.0)
     out_ref[...] = jnp.dot((s / denom).astype(w_ref.dtype), w_ref[...],
                            preferred_element_type=jnp.float32
@@ -190,15 +195,16 @@ def _csr_agg_kernel(idx_ref, seg_ref, ew_ref, h_ref, w_ref, out_ref):
 
 
 def graph_agg_csr_pallas(h, idx_slab, seg_slab, ew_slab, w, n_dst: int, *,
-                         interpret: bool = True):
+                         interpret: bool):
     """CSR segment-mean + matmul over the planned slab layout.
 
     h: (n_src, d); idx/seg/ew slabs: (n_tiles*slab, 1) from
     ``graph.csr_plan.plan_csr_slabs`` / ``ell_to_slabs``; w: (d, d_out)
     -> (n_dst, d_out).
     Grid is (dst tiles, d_out tiles); each program reads ONE tile's edge
-    slab and one weight tile — VMEM per program is slab·(2·4B) + n_src·d·4B
-    for the shared source rows + the (128, slab) assignment matrix.
+    slab — its (slab, d) gathered source rows plus (1, slab) local rows and
+    weights — and one weight tile. VMEM per program is slab·d·4B + the
+    (128, slab) assignment matrix, independent of n_src.
     """
     d = h.shape[1]
     d_out = w.shape[1]
@@ -206,19 +212,22 @@ def graph_agg_csr_pallas(h, idx_slab, seg_slab, ew_slab, w, n_dst: int, *,
     wp = _pad_cols(w, bo)
     n_tiles = max(1, -(-n_dst // DST_BLOCK))
     slab = idx_slab.shape[0] // n_tiles
+    gathered = jnp.take(h.astype(jnp.float32), idx_slab[:, 0], axis=0)
+    # lane-major (n_tiles, 1, slab) rows: a tile's block is one (1, slab)
+    # row vector, compared against the sublane iota without a transpose
+    seg = seg_slab.reshape(n_tiles, 1, slab)
+    ew = ew_slab.reshape(n_tiles, 1, slab)
     grid = (n_tiles, wp.shape[1] // bo)
     out = pl.pallas_call(
         _csr_agg_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((slab, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),                # edge srcs
-            pl.BlockSpec((slab, 1), lambda i, j: (i, 0),
+            pl.BlockSpec((pl.Squeezed(), 1, slab), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),                # local rows
-            pl.BlockSpec((slab, 1), lambda i, j: (i, 0),
+            pl.BlockSpec((pl.Squeezed(), 1, slab), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),                # weights
-            pl.BlockSpec((h.shape[0], d), lambda i, j: (0, 0),
-                         memory_space=pltpu.VMEM),                # sources
+            pl.BlockSpec((slab, d), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM),                # edge rows
             pl.BlockSpec((d, bo), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),                # W tile
         ],
@@ -227,78 +236,67 @@ def graph_agg_csr_pallas(h, idx_slab, seg_slab, ew_slab, w, n_dst: int, *,
         out_shape=jax.ShapeDtypeStruct((n_tiles * DST_BLOCK, wp.shape[1]),
                                        w.dtype),
         interpret=interpret,
-    )(idx_slab, seg_slab, ew_slab, h, wp)
+    )(seg, ew, gathered, wp)
     return out[:n_dst, :d_out]
 
 
 # -------------------------------------------------------------------- GCNII
-def _gcnii_kernel(idx_ref, mask_ref, h_ref, h0_ref, w_ref, b_ref, col_ref,
-                  out_ref, *, alpha, beta, block_out):
+def _gcnii_kernel(idx_ref, mask_ref, h_ref, h0_ref, w_ref, b_ref, out_ref, *,
+                  alpha):
     agg = _masked_mean(idx_ref, mask_ref, h_ref)
     # initial residual: H0 at the output node set (self column, unmasked —
     # mirrors the reference's plain h0[idx[:, 0]] gather)
-    sel0 = _select_matrix(idx_ref[...][:, 0], h0_ref.shape[0])
+    sel0 = _select_matrix(idx_ref[...][:, 0:1], h0_ref.shape[0])
     h0_sel = jnp.dot(sel0, h0_ref[...].astype(jnp.float32),
                      preferred_element_type=jnp.float32)
-    z = (1.0 - alpha) * agg + alpha * h0_sel                 # (BD, d_pad)
-    zw = jnp.dot(z.astype(w_ref.dtype), w_ref[...],
-                 preferred_element_type=jnp.float32)
-    # identity-map skip needs z restricted to this output tile's columns.
-    # col_ref carries the tile's column offset as data (a (1, 1) block of an
-    # offsets array indexed by the column grid axis) instead of
-    # pl.program_id(1) — vmap over the client axis prepends a grid dimension
-    # and would silently shift program_id axes.
-    z_cols = jax.lax.dynamic_slice_in_dim(z, col_ref[0, 0], block_out, axis=1)
-    out = (1.0 - beta) * z_cols + beta * zw + b_ref[...].astype(jnp.float32)
-    out_ref[...] = jax.nn.relu(out).astype(out_ref.dtype)
+    z = (1.0 - alpha) * agg + alpha * h0_sel                 # (BD, d)
+    out = jnp.dot(z.astype(w_ref.dtype), w_ref[...],
+                  preferred_element_type=jnp.float32)
+    out_ref[...] = jax.nn.relu(
+        out + b_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 def gcnii_layer_pallas(h, h0, idx, mask, w, b, *, alpha: float, beta: float,
-                       interpret: bool = True):
+                       interpret: bool):
     """Fused GCNII client sub-layer (constant width d == d_out).
 
     h/h0: (n_src, d); idx/mask: (n_dst, F+1) with self at column 0;
     w: (d, d); b: (d,) -> relu((1-β)z + β(z@W) + b), z = (1-α)·mean + α·h0.
+    The identity-map skip is folded into the weight, (1-β)z + β(z@W) =
+    z @ ((1-β)I + βW), so each (dst tile, d_out tile) program needs no
+    column offset into z: the skip's columns arrive with the weight tile.
     """
     n_dst, fanout1 = idx.shape
     d = h.shape[1]
     bo = _dout_block(d)
-    hp = _pad_cols(h, bo)
-    h0p = _pad_cols(h0, bo)
-    wp = _pad_cols(_pad_rows(w, bo), bo)
+    w_id = (1.0 - beta) * jnp.eye(d, dtype=w.dtype) + beta * w
+    wp = _pad_cols(w_id, bo)
     bp = _pad_cols(b[None, :], bo)
     idx = _pad_rows(idx, DST_BLOCK)
     mask = _pad_rows(mask, DST_BLOCK)
-    d_pad = hp.shape[1]
-    n_col_tiles = d_pad // bo
-    col_offsets = (jnp.arange(n_col_tiles, dtype=jnp.int32) * bo)[:, None]
-    grid = (idx.shape[0] // DST_BLOCK, n_col_tiles)
+    grid = (idx.shape[0] // DST_BLOCK, wp.shape[1] // bo)
     out = pl.pallas_call(
-        functools.partial(_gcnii_kernel, alpha=alpha, beta=beta,
-                          block_out=bo),
+        functools.partial(_gcnii_kernel, alpha=alpha),
         grid=grid,
         in_specs=[
             pl.BlockSpec((DST_BLOCK, fanout1), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((DST_BLOCK, fanout1), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((hp.shape[0], d_pad), lambda i, j: (0, 0),
+            pl.BlockSpec((h.shape[0], d), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((h0p.shape[0], d_pad), lambda i, j: (0, 0),
+            pl.BlockSpec((h0.shape[0], d), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((d_pad, bo), lambda i, j: (0, j),
+            pl.BlockSpec((d, bo), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bo), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
-            # column offset: a (1, 1) scalar tile, SMEM by the guide idiom
-            pl.BlockSpec((1, 1), lambda i, j: (j, 0),
-                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((DST_BLOCK, bo), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((idx.shape[0], d_pad), w.dtype),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], wp.shape[1]), w.dtype),
         interpret=interpret,
-    )(idx, mask, hp, h0p, wp, bp, col_offsets)
+    )(idx, mask, h, h0, wp, bp)
     return out[:n_dst, :d]
 
 
@@ -323,7 +321,7 @@ def _gat_kernel(idx_ref, mask_ref, h_ref, w_ref, asrc_ref, adst_ref, b_ref,
     gathered = []
     e = jnp.zeros((n_dst, f1), jnp.float32)
     for f in range(f1):  # glint: disable=GL004 static fanout unroll at trace time (F is 3-64; see module docstring)
-        sel = _select_matrix(idx[:, f], n_src)
+        sel = _select_matrix(idx[:, f:f + 1], n_src)
         gathered.append(jnp.dot(sel, wh, preferred_element_type=jnp.float32))
         ecol = jnp.dot(sel, e_dst, preferred_element_type=jnp.float32)
         e = e + jnp.where(cols == f, ecol, 0.0)
@@ -335,26 +333,34 @@ def _gat_kernel(idx_ref, mask_ref, h_ref, w_ref, asrc_ref, adst_ref, b_ref,
     out = jnp.zeros_like(gathered[0])
     for f in range(f1):
         out = out + att[:, f:f + 1] * gathered[f]
-    out_ref[...] = jax.nn.elu(
-        out + b_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+    out = out + b_ref[...].astype(jnp.float32)
+    # elu spelled with exp: Mosaic has no expm1 lowering
+    out = jnp.where(out > 0, out, jnp.exp(jnp.minimum(out, 0.0)) - 1.0)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
-def gat_layer_pallas(h, idx, mask, w, a_src, a_dst, b, *,
-                     interpret: bool = True):
+def gat_layer_pallas(h, idx, mask, w, a_src, a_dst, b, *, interpret: bool):
     """Fused multi-head GAT client sub-layer.
 
     h: (n_src, d); idx/mask: (n_dst, F+1) with self at column 0;
     w: (d, H, dh); a_src/a_dst: (H, dh); b: (H*dh,) -> (n_dst, H*dh).
     Grid is (dst tiles, heads): each program handles one head's (128, dh)
     output block; the head axis rides the BlockSpec index maps so no head
-    dimension is ever materialized in VMEM.
+    dimension is ever materialized in VMEM. Each head's width is
+    zero-padded to a lane multiple (128) so its blocks tile the chip's
+    (8, 128) layout: padded columns project to 0, add nothing to the
+    attention logits, and are sliced off the output.
     """
     n_dst, fanout1 = idx.shape
     d, n_heads, dh = w.shape
     idx = _pad_rows(idx, DST_BLOCK)
     mask = _pad_rows(mask, DST_BLOCK)
-    w2 = w.reshape(d, n_heads * dh)
-    b2 = b.reshape(1, n_heads * dh)
+    wp = _pad_cols(w, LANE)
+    dhp = wp.shape[2]
+    w2 = wp.reshape(d, n_heads * dhp)
+    a_src2 = _pad_cols(a_src, LANE).reshape(1, n_heads * dhp)
+    a_dst2 = _pad_cols(a_dst, LANE).reshape(1, n_heads * dhp)
+    b2 = _pad_cols(b.reshape(n_heads, dh), LANE).reshape(1, n_heads * dhp)
     grid = (idx.shape[0] // DST_BLOCK, n_heads)
     out = pl.pallas_call(
         _gat_kernel,
@@ -366,18 +372,19 @@ def gat_layer_pallas(h, idx, mask, w, a_src, a_dst, b, *,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((h.shape[0], d), lambda i, k: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((d, dh), lambda i, k: (0, k),
+            pl.BlockSpec((d, dhp), lambda i, k: (0, k),
                          memory_space=pltpu.VMEM),            # head's W
-            pl.BlockSpec((1, dh), lambda i, k: (k, 0),
+            pl.BlockSpec((1, dhp), lambda i, k: (0, k),
                          memory_space=pltpu.VMEM),            # head's a_src
-            pl.BlockSpec((1, dh), lambda i, k: (k, 0),
+            pl.BlockSpec((1, dhp), lambda i, k: (0, k),
                          memory_space=pltpu.VMEM),            # head's a_dst
-            pl.BlockSpec((1, dh), lambda i, k: (0, k),
+            pl.BlockSpec((1, dhp), lambda i, k: (0, k),
                          memory_space=pltpu.VMEM),            # head's bias
         ],
-        out_specs=pl.BlockSpec((DST_BLOCK, dh), lambda i, k: (i, k),
+        out_specs=pl.BlockSpec((DST_BLOCK, dhp), lambda i, k: (i, k),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((idx.shape[0], n_heads * dh), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], n_heads * dhp), h.dtype),
         interpret=interpret,
-    )(idx, mask, h, w2, a_src, a_dst, b2)
-    return out[:n_dst]
+    )(idx, mask, h, w2, a_src2, a_dst2, b2)
+    out = out.reshape(idx.shape[0], n_heads, dhp)[:n_dst, :, :dh]
+    return out.reshape(n_dst, n_heads * dh)

@@ -46,10 +46,8 @@ def make_client_mesh(n_clients: int, *, max_devices=None, devices=None):
             raise ValueError(f"max_devices must be >= 1, got {max_devices}")
         devs = devs[:max_devices]
     d = client_mesh_size(n_clients, len(devs))
-    return jax.make_mesh((d,), ("clients",), devices=devs[:d])
-
-
-# v5e hardware constants for the roofline analysis
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
+    # Auto, not the Explicit default of newer JAX: the round builders mix
+    # client-sharded params with replicated inputs under vmap/shard_map and
+    # leave the placement to the compiler
+    return jax.make_mesh((d,), ("clients",), devices=devs[:d],
+                         axis_types=(jax.sharding.AxisType.Auto,))

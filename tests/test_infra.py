@@ -6,6 +6,7 @@ import pytest
 
 from repro.core import checkpoint
 from repro.launch import hlo_cost
+from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
 
 
 # ------------------------------------------------------------- checkpointing
@@ -183,3 +184,23 @@ def test_dryrun_appends_to_user_xla_flags():
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=".",
                          capture_output=True, text=True, check=True).stdout
     assert out.strip() == "--xla_force_host_platform_device_count=4"
+
+
+# --------------------------------------------------------- compile cache
+@pytest.mark.parametrize("env_dir", [None, "from_env"])
+def test_enable_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache
+    sits at the fixed ``<repo>/.jax_cache`` (never a per-run path)."""
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(REPO_CACHE_DIR)
+        assert REPO_CACHE_DIR.parent.joinpath("chip_smoke.py").is_file()
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -100,7 +100,7 @@ def _walk_eqns(jaxpr):
 
 
 def _sub_jaxprs(v):
-    import jax.core as core
+    import jax.extend.core as core
     if isinstance(v, core.ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, core.Jaxpr):
@@ -393,7 +393,8 @@ def _ep_graph_agg_kernel():
     idx = jax.ShapeDtypeStruct((16, 3), "int32")
     mask = jax.ShapeDtypeStruct((16, 3), "float32")
     w = jax.ShapeDtypeStruct((8, 8), "float32")
-    closed = jax.make_jaxpr(graph_agg_pallas)(h, idx, mask, w)
+    closed = jax.make_jaxpr(
+        functools.partial(graph_agg_pallas, interpret=True))(h, idx, mask, w)
     return closed, None
 
 
@@ -413,7 +414,8 @@ def _ep_graph_agg_csr_kernel():
              for a in (idx_s, seg_s, ew_s)]
     closed = jax.make_jaxpr(
         lambda h_, i_, s_, e_, w_: graph_agg_csr_pallas(h_, i_, s_, e_, w_,
-                                                        n_dst))(
+                                                        n_dst,
+                                                        interpret=True))(
             h, *slabs, w)
     return closed, None
 
@@ -427,7 +429,8 @@ def _ep_gcnii_kernel():
     w = jax.ShapeDtypeStruct((8, 8), "float32")
     b = jax.ShapeDtypeStruct((8,), "float32")
     closed = jax.make_jaxpr(
-        lambda *a: gcnii_layer_pallas(*a, alpha=0.1, beta=0.5))(
+        lambda *a: gcnii_layer_pallas(*a, alpha=0.1, beta=0.5,
+                                      interpret=True))(
             h, h, idx, mask, w, b)
     return closed, None
 
@@ -438,7 +441,8 @@ def _ep_flash_kernel():
     q = jax.ShapeDtypeStruct((1, 64, 4, 8), "float32")
     k = jax.ShapeDtypeStruct((1, 64, 2, 8), "float32")   # GQA: 2 kv heads
     closed = jax.make_jaxpr(
-        lambda q_, k_, v_: flash_attention_pallas(q_, k_, v_))(q, k, k)
+        lambda q_, k_, v_: flash_attention_pallas(q_, k_, v_,
+                                                  interpret=True))(q, k, k)
     return closed, None
 
 
@@ -452,8 +456,9 @@ def _ep_gat_kernel():
     a_src = jax.ShapeDtypeStruct((2, 4), "float32")
     a_dst = jax.ShapeDtypeStruct((2, 4), "float32")
     b = jax.ShapeDtypeStruct((8,), "float32")
-    closed = jax.make_jaxpr(gat_layer_pallas)(h, idx, mask, w, a_src,
-                                              a_dst, b)
+    closed = jax.make_jaxpr(
+        functools.partial(gat_layer_pallas, interpret=True))(
+            h, idx, mask, w, a_src, a_dst, b)
     return closed, None
 
 
