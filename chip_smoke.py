@@ -77,34 +77,19 @@ def _fail(msg: str):
     raise SystemExit(f"chip_smoke: FAIL: {msg}")
 
 
-class CompileMeter:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events (a cache hit's retrieval counts as its compile)."""
+def _compile_counts():
+    """(compile seconds, persistent-cache hits, misses) so far, from the
+    program's compile counter (``repro.obs``)."""
+    from repro import obs
+    c = obs.counters()
+    return (c.get("compile_s", 0.0), c.get("compile_cache_hits", 0),
+            c.get("compile_cache_misses", 0))
 
-    def __init__(self, jax):
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.seconds, self.hits, self.misses
-
-    def report(self, phase: str, since):
-        s, h, m = since
-        print(f"[{phase}] compile_s={self.seconds - s:.2f} "
-              f"cache_hits={self.hits - h} cache_misses={self.misses - m}")
+def _report(phase: str, since):
+    (s, h, m), (s1, h1, m1) = since, _compile_counts()
+    print(f"[{phase}] compile_s={s1 - s:.2f} "
+          f"cache_hits={h1 - h} cache_misses={m1 - m}")
 
 
 def _run(cfg):
@@ -283,33 +268,33 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     print(f"compile cache dir={enable_compile_cache()}")
-    meter = CompileMeter(jax)
     t_start = time.perf_counter()
 
     if args.chips == 4:
-        since = meter.snapshot()
+        since = _compile_counts()
         phase_sharded(4)
-        meter.report("sharded", since)
+        _report("sharded", since)
     else:
         ckpt_dir = tempfile.mkdtemp(prefix=".chip_smoke_ckpt_", dir=REPO)
         try:
-            since = meter.snapshot()
+            since = _compile_counts()
             cfg, losses = phase_train(ckpt_dir)
-            meter.report("train", since)
-            since = meter.snapshot()
+            _report("train", since)
+            since = _compile_counts()
             phase_cpu(cfg, losses)
-            meter.report("cpu", since)
-            since = meter.snapshot()
+            _report("cpu", since)
+            since = _compile_counts()
             phase_fused()
-            meter.report("fused", since)
-            since = meter.snapshot()
+            _report("fused", since)
+            since = _compile_counts()
             phase_serve(ckpt_dir)
-            meter.report("serve", since)
+            _report("serve", since)
         finally:
             shutil.rmtree(ckpt_dir, ignore_errors=True)
 
-    print(f"total compile_s={meter.seconds:.2f} cache_hits={meter.hits} "
-          f"cache_misses={meter.misses} wall_s="
+    seconds, hits, misses = _compile_counts()
+    print(f"total compile_s={seconds:.2f} cache_hits={hits} "
+          f"cache_misses={misses} wall_s="
           f"{time.perf_counter() - t_start:.2f}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

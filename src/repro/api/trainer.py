@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core import checkpoint, glasu
 from ..core.train import TrainResult, _eval_tables, make_centralized_dataset
 from ..fed.faults import make_schedule
@@ -513,40 +514,52 @@ class Trainer:
         schedule = step_schedule(st.round, cfg.rounds, cfg.rounds_per_step,
                                  (cfg.eval_every,) + ckpt_cadences)
         prefetch = PrefetchSampler(self.sampler, schedule,
-                                   n_buffers=cfg.prefetch_buffers) \
-            if schedule else None
+                                   n_buffers=cfg.prefetch_buffers,
+                                   start=st.round) if schedule else None
         try:
             t = st.round
             for _ in schedule:
-                step = prefetch.get()
-                k = step.rounds
-                keys = _fold_keys(key, jnp.arange(t, t + k))
-                batches = jax.device_put(step.data)
-                plans = self.fault_sched.draw_step(k) \
-                    if self.fault_sched is not None else None
-                out = self._run_step(st.params, st.opt_state, batches, keys,
-                                     faults=plans)
-                st.params, st.opt_state = out.params, out.opt_state
-                st.sampler_rng_state = step.rng_state_after
-                # recycles the oldest generation, blocking on ITS compute
-                # only — the step just dispatched keeps running
-                prefetch.retire(step, out.losses)
-                logs = out.message_logs
-                per_round_bytes = out.comm_bytes_rounds
-                for i in range(k):
-                    st.round = t + i + 1
-                    # a device row, not a host value: nothing blocks until
-                    # EvalHook pulls it at eval cadence
-                    st.last_losses = out.losses[i]
-                    metrics = {"round": st.round, "losses": out.losses[i],
-                               "comm_bytes_round":
-                                   per_round_bytes[i]
-                                   if per_round_bytes is not None
-                                   else out.comm_bytes_round,
-                               "message_log": logs[i] if logs else None,
-                               "fault_plan": plans[i] if plans else None}
-                    for h in self.hooks:
-                        h.on_round_end(self, metrics)
+                # spans (repro.obs), keyed by the step's first round:
+                # wait_sample, stage, dispatch, wait_device (in retire)
+                # and hook, inside step
+                with obs.span("step", key=t + 1):
+                    with obs.span("wait_sample"):
+                        step = prefetch.get()
+                    k = step.rounds
+                    with obs.span("stage"):
+                        keys = _fold_keys(key, jnp.arange(t, t + k))
+                        batches = jax.device_put(step.data)
+                        plans = self.fault_sched.draw_step(k) \
+                            if self.fault_sched is not None else None
+                        obs.count("stage_bytes", sum(
+                            x.nbytes for x in jax.tree.leaves(step.data)))
+                    with obs.span("dispatch"):
+                        out = self._run_step(st.params, st.opt_state,
+                                             batches, keys, faults=plans)
+                    st.params, st.opt_state = out.params, out.opt_state
+                    st.sampler_rng_state = step.rng_state_after
+                    # recycles the oldest generation, blocking on ITS
+                    # compute only — the step just dispatched keeps running
+                    prefetch.retire(step, out.losses)
+                    logs = out.message_logs
+                    per_round_bytes = out.comm_bytes_rounds
+                    with obs.span("hook"):
+                        for i in range(k):
+                            st.round = t + i + 1
+                            # a device row, not a host value: nothing
+                            # blocks until EvalHook pulls it at eval cadence
+                            st.last_losses = out.losses[i]
+                            metrics = {
+                                "round": st.round, "losses": out.losses[i],
+                                "comm_bytes_round":
+                                    per_round_bytes[i]
+                                    if per_round_bytes is not None
+                                    else out.comm_bytes_round,
+                                "message_log": logs[i] if logs else None,
+                                "fault_plan": plans[i] if plans else None}
+                            for h in self.hooks:
+                                h.on_round_end(self, metrics)
+                    obs.count("rounds", k)
                 t += k
                 if st.should_stop:
                     break

@@ -32,6 +32,7 @@ from typing import List, NamedTuple, Sequence
 import jax
 import numpy as np
 
+from .. import obs
 from .sampler import GlasuSampler, SampledBatch
 
 
@@ -75,11 +76,12 @@ class PrefetchSampler:
     """
 
     def __init__(self, sampler: GlasuSampler, schedule: Sequence[int],
-                 n_buffers: int = 2):
+                 n_buffers: int = 2, start: int = 0):
         if any(k < 1 for k in schedule):
             raise ValueError(f"step schedule must be positive: {schedule}")
         self.sampler = sampler
         self.schedule = list(schedule)
+        self.start = start              # round before the first step
         self.n_buffers = max(1, min(int(n_buffers), len(self.schedule)))
         k_max = max(self.schedule, default=0)
         self._bufs: List[SampledBatch] = [
@@ -117,22 +119,32 @@ class PrefetchSampler:
 
     # -------------------------------------------------------------- worker
     def _work(self):
+        """Per step: span ``fill`` (keyed by the step's first round)
+        around ``wait_free`` (every generation in flight), one ``sample``
+        per round, and the copies into the generation (``fill``'s own
+        time)."""
         try:
+            t = self.start
             for k in self.schedule:
-                gen = self._free.get()
-                if gen == _STOP or self._stop.is_set():
-                    return
-                buf = self._bufs[gen]
-                view = unstack_round(buf, slice(0, k))
-                for i in range(k):
-                    if self._stop.is_set():  # close() mid-fill: exit promptly
-                        return               # instead of finishing the step
-                    b = self.sampler.sample_round()
-                    jax.tree.map(lambda dst, src, i=i: np.copyto(dst[i], src),
-                                 view, b)
-                state = copy.deepcopy(
-                    self.sampler.rng.bit_generator.state)
-                self._out.put(StepBatch(view, k, gen, state))
+                with obs.span("fill", key=t + 1):
+                    with obs.span("wait_free"):
+                        gen = self._free.get()
+                    if gen == _STOP or self._stop.is_set():
+                        return
+                    buf = self._bufs[gen]
+                    view = unstack_round(buf, slice(0, k))
+                    for i in range(k):
+                        if self._stop.is_set():  # close() mid-fill: exit
+                            return               # promptly, step unfinished
+                        with obs.span("sample"):
+                            b = self.sampler.sample_round()
+                        jax.tree.map(
+                            lambda dst, src, i=i: np.copyto(dst[i], src),
+                            view, b)
+                    state = copy.deepcopy(
+                        self.sampler.rng.bit_generator.state)
+                    self._out.put(StepBatch(view, k, gen, state))
+                t += k
         except BaseException as e:          # propagate to the consumer
             self._out.put(_WorkerError(e))
 
@@ -151,7 +163,8 @@ class PrefetchSampler:
         while len(self._inflight) >= self.n_buffers:
             gen, handle = self._inflight.pop(0)
             if handle is not None:
-                jax.block_until_ready(handle)
+                with obs.span("wait_device"):
+                    jax.block_until_ready(handle)
             self._free.put(gen)
 
     def close(self) -> None:

@@ -17,6 +17,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+from .. import obs
 from .metrics import ServeAnswer
 
 
@@ -28,7 +29,8 @@ class MicroBatcher:
                           else session.serve.max_batch)
         self.deadline_s = (deadline_ms if deadline_ms is not None
                            else session.serve.batch_deadline_ms) / 1e3
-        self._queue: List[Tuple[np.ndarray, Future]] = []
+        # (nodes, future, submit time on time.perf_counter)
+        self._queue: List[Tuple[np.ndarray, Future, float]] = []
         self._cv = threading.Condition()
         self._closed = False
         self.batches = 0          # dispatches issued
@@ -42,7 +44,7 @@ class MicroBatcher:
         with self._cv:
             if self._closed:
                 raise RuntimeError("batcher is closed")
-            self._queue.append((nodes, fut))
+            self._queue.append((nodes, fut, time.perf_counter()))
             self._cv.notify()
         return fut
 
@@ -59,7 +61,7 @@ class MicroBatcher:
             if not self._queue:
                 return []
             deadline = time.monotonic() + self.deadline_s
-            while (sum(len(n) for n, _ in self._queue) < self.max_batch):
+            while (sum(len(n) for n, _, _ in self._queue) < self.max_batch):
                 left = deadline - time.monotonic()
                 if left <= 0 or self._closed:
                     break
@@ -76,15 +78,19 @@ class MicroBatcher:
                 continue
             self.batches += 1
             self.coalesced += len(batch) - 1
-            all_nodes = np.concatenate([n for n, _ in batch])
+            all_nodes = np.concatenate([n for n, _, _ in batch])
+            # each request's wait, from submit to its dispatch's start
+            now = time.perf_counter()
+            for _, _, t_submit in batch:
+                obs.record("queue", t_submit, now, key=self.batches)
             try:
                 ans = self.session.answer(all_nodes)
             except Exception as e:           # noqa: BLE001 — fan the
-                for _, fut in batch:         # failure out to every waiter
+                for _, fut, _ in batch:      # failure out to every waiter
                     fut.set_exception(e)
                 continue
             off = 0
-            for nodes, fut in batch:
+            for nodes, fut, _ in batch:
                 sl = slice(off, off + len(nodes))
                 off += len(nodes)
                 fut.set_result(ServeAnswer(

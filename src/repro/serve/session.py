@@ -51,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..comm.compression import make_compressor
 from ..core import checkpoint, glasu
 from ..core.train import _eval_neighbor_tables, _eval_tables
@@ -368,7 +369,8 @@ class InferenceSession:
         answers = []
         with self._lock:
             for c in chunks:
-                ans = self._answer_locked(c)
+                with obs.span("answer"):
+                    ans = self._answer_locked(c)
                 self.metrics.record(ans)
                 answers.append(ans)
         if len(answers) == 1:
@@ -392,6 +394,10 @@ class InferenceSession:
             log=answers[0].log)
 
     def _answer_locked(self, nodes: np.ndarray) -> ServeAnswer:
+        """One dispatch, inside the caller's span ``answer``
+        (``repro.obs``), with spans ``cache`` (the top-layer probe),
+        ``plan`` (``_build_plan``), ``forward`` (``_fwd`` and its copy to
+        the host) and ``classify`` (``_cls`` and its copy to the host)."""
         t0 = time.perf_counter()
         m = self.mcfg
         uniq, inv = np.unique(nodes, return_inverse=True)
@@ -400,8 +406,9 @@ class InferenceSession:
         top = self.L - 1 if self.mcfg.agg_layers else None
 
         if top is not None:
-            top_hit, top_rows = self.cache.lookup(
-                top, uniq, self.params_version, (self.M, self.h_agg))
+            with obs.span("cache"):
+                top_hit, top_rows = self.cache.lookup(
+                    top, uniq, self.params_version, (self.M, self.h_agg))
         else:
             top_hit = np.zeros(b, dtype=np.float32)
             top_rows = np.zeros((b, self.M, self.h_agg), dtype=np.float32)
@@ -413,14 +420,16 @@ class InferenceSession:
             fresh = {l: 0 for l in m.agg_layers}
             cold = False
         else:
-            plan = self._build_plan(uniq, bucket, top_hit, top_rows)
-            h, aggs = self._fwd(self.params, plan.batch, plan.inject)
-            # numpy roundtrip on purpose: the warm path assembles the same
-            # f32 rows from cache, so both paths feed the classifier
-            # bitwise-identical arrays
-            rows = np.ascontiguousarray(
-                np.asarray(h).transpose(1, 0, 2)).astype(
-                    np.float32, copy=False)
+            with obs.span("plan"):
+                plan = self._build_plan(uniq, bucket, top_hit, top_rows)
+            with obs.span("forward"):
+                h, aggs = self._fwd(self.params, plan.batch, plan.inject)
+                # numpy roundtrip on purpose: the warm path assembles the
+                # same f32 rows from cache, so both paths feed the
+                # classifier bitwise-identical arrays
+                rows = np.ascontiguousarray(
+                    np.asarray(h).transpose(1, 0, 2)).astype(
+                        np.float32, copy=False)
             for l, (ids_l, comp) in plan.fills.items():
                 if comp.any():
                     stack = np.asarray(aggs[l])        # (M, n, h_agg)
@@ -433,12 +442,13 @@ class InferenceSession:
 
         real = np.zeros(bucket, dtype=np.float32)
         real[:b] = 1.0
-        per, ens = self._cls(self.params,
-                             jnp.asarray(np.ascontiguousarray(
-                                 rows.transpose(1, 0, 2))),
-                             jnp.asarray(real))
-        per = np.asarray(per)[:, :b, :][:, inv, :]
-        ens = np.asarray(ens)[:b][inv]
+        with obs.span("classify"):
+            per, ens = self._cls(self.params,
+                                 jnp.asarray(np.ascontiguousarray(
+                                     rows.transpose(1, 0, 2))),
+                                 jnp.asarray(real))
+            per = np.asarray(per)[:, :b, :][:, inv, :]
+            ens = np.asarray(ens)[:b][inv]
         up, down, idx = self._price(fresh)
         # hit/miss on the answer are the top-layer probe's outcome — the
         # decision that picks warm vs cold; inner-layer hits show up in
