@@ -12,7 +12,9 @@ hidden 64, K=2, Q=4):
 
 1. ``Trainer`` on the preset as shipped (vmapped backend, XLA path), a few
    dozen rounds in multi-round steps, one exact eval and a checkpoint:
-   losses must be finite and falling.
+   losses must be finite and falling; the eval's layers take the resident
+   aggregation kernel, which must match XLA's ``gather_mean`` on the chip
+   within ``RESIDENT_RTOL`` (whether bitwise is printed).
 2. The first round again on the host CPU backend: its losses must agree
    with the chip's within ``CPU_RTOL``.
 3. One round of each backbone (gcn, gcnii, gat) with ``use_pallas=True``
@@ -23,8 +25,11 @@ hidden 64, K=2, Q=4):
 
 ``--chips 4`` runs only the sharded backend with one party per chip
 (``n_clients=4``), checks the client mesh spans 4 devices and the
-collective byte meter, and compares its first round's losses with the
-vmapped backend on one chip within ``SHARD_RTOL``.
+collective byte meter, checks that the exact eval, split by party over
+the chips, kept XLA's gather (XLA cannot split the resident kernel) and
+gives the logits of the same params on one chip, where the resident
+kernel runs, within ``EVAL_RTOL``, and compares its first round's losses
+with the vmapped backend on one chip within ``SHARD_RTOL``.
 """
 from __future__ import annotations
 
@@ -71,6 +76,13 @@ FUSED_RTOL = 2e-2
 # Sharded vs vmapped on the same chip type: identical math, different
 # fusion and per-device batch widths, so only accumulation order differs.
 SHARD_RTOL = 2e-2
+# The resident aggregation kernel against XLA's gather_mean on the chip,
+# as a share of the largest mean: float32 sums in another order at most.
+RESIDENT_RTOL = 1e-6
+# The exact eval split over the chips (XLA's gather) against one chip (the
+# resident kernel), as a share of the largest logit: the same sums, which
+# may round differently, and then the same one-pass bfloat16 matmuls.
+EVAL_RTOL = 2e-2
 
 
 def _fail(msg: str):
@@ -130,6 +142,7 @@ def _compare(name: str, got, want, rtol: float):
 
 
 def phase_train(ckpt_dir: str):
+    import jax
     import numpy as np
     from repro.api import get_preset
 
@@ -138,8 +151,12 @@ def phase_train(ckpt_dir: str):
         ckpt_dir=ckpt_dir)
     if cfg.use_pallas or cfg.backend != "vmapped":
         _fail(f"{PRESET} no longer ships as the vmapped XLA path")
+    from repro import obs
+    before = obs.counters()
     t0 = time.perf_counter()
-    _, result, losses = _run(cfg)
+    trainer, result, losses = _run(cfg)
+    paths = {k: obs.counters().get(k, 0) - before.get(k, 0)
+             for k in ("full_agg:resident", "full_agg:xla")}
     per_round = losses.mean(axis=1)
     head = float(per_round[:ROUNDS_PER_STEP].mean())
     tail = float(per_round[-ROUNDS_PER_STEP:].mean())
@@ -153,7 +170,48 @@ def phase_train(ckpt_dir: str):
         _fail(f"losses not falling: first step {head:.4f}, last {tail:.4f}")
     if len(result.history) != 1:
         _fail(f"expected one eval entry, got {len(result.history)}")
+    # the exact eval's layers take the VMEM-resident mean on a TPU
+    print(f"[train] eval layers resident={paths['full_agg:resident']} "
+          f"xla={paths['full_agg:xla']}")
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and (paths["full_agg:xla"] or not paths["full_agg:resident"]):
+        _fail(f"exact eval took the XLA aggregation: {paths}")
+    _check_resident_order(trainer)
     return cfg, losses
+
+
+def _check_resident_order(trainer):
+    """The resident kernel against ``gather_mean`` run by XLA on the chip,
+    on the eval's own neighbour tables and on tables of other widths:
+    equal to ``RESIDENT_RTOL``, and bitwise where the kernel's summation
+    order is XLA's (printed, not required)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.train import _eval_neighbor_tables
+    from repro.kernels import ops as kops
+    from repro.models.gnn import gather_mean
+
+    cfg = trainer.cfg
+    idx, mask = _eval_neighbor_tables(trainer.data, cfg.eval_table_cap,
+                                      cfg.seed)
+    m, n, width = idx.shape
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(m, n, trainer.model_cfg.hidden)),
+                    jnp.float32)
+    tables = {width: (idx, mask)}
+    for f in (5, 12):
+        tables[f] = (jnp.asarray(rng.integers(0, n, (m, n, f)), jnp.int32),
+                     jnp.asarray(rng.random((m, n, f)) < 0.8, jnp.float32))
+    xla = jax.jit(jax.vmap(gather_mean))
+    for f, (i, k) in tables.items():
+        got = np.asarray(kops.resident_masked_mean(h, i, k))
+        want = np.asarray(xla(h, i, k))
+        rel = float(np.abs(got - want).max() / np.abs(want).max())
+        print(f"[train] resident vs xla mean width={f}: max_rel={rel:.3e} "
+              f"bitwise={bool((got == want).all())}")
+        if not rel <= RESIDENT_RTOL:
+            _fail(f"resident mean at width {f} off by {rel:.3e}")
 
 
 def phase_cpu(cfg, chip_losses):
@@ -211,6 +269,33 @@ def phase_serve(ckpt_dir: str):
             _fail(f"batch {i}: non-finite logits")
 
 
+def _compare_eval(trainer):
+    """The exact eval's logits on the trainer's party-split params (XLA's
+    gather) against the same params, fresh on one chip (the resident
+    kernel)."""
+    import jax
+    import numpy as np
+    from repro import obs
+    from repro.api.trainer import EvalHook
+
+    hook = next(h for h in trainer.hooks if isinstance(h, EvalHook))
+    params = trainer.state.params
+    split = np.asarray(hook.eval_fn(params))
+    before = obs.counters().get("full_agg:resident", 0)
+    # from the host: an array moved between devices keeps its mesh type
+    one = np.asarray(hook.eval_fn(
+        jax.device_put(jax.device_get(params), jax.devices()[0])))
+    resident = obs.counters().get("full_agg:resident", 0) - before
+    err = float(np.abs(split - one).max())
+    scale = float(np.abs(one).max())
+    print(f"[sharded] eval logits split (xla) vs one chip (resident="
+          f"{resident}): max_abs_diff={err:.3e} max_abs={scale:.3e}")
+    if not resident:
+        _fail("the one-chip exact eval did not take the resident kernel")
+    if not err <= EVAL_RTOL * scale:
+        _fail(f"split exact eval differs from one chip's by {err:.3e}")
+
+
 def phase_sharded(n_chips: int):
     import jax
     from repro.api import get_preset
@@ -218,8 +303,12 @@ def phase_sharded(n_chips: int):
     cfg = get_preset(PRESET).with_(
         n_clients=n_chips, backend="sharded", rounds=SHARD_ROUNDS,
         rounds_per_step=ROUNDS_PER_STEP, eval_every=SHARD_ROUNDS)
+    from repro import obs
+    before = obs.counters()
     t0 = time.perf_counter()
     trainer, result, sharded = _run(cfg)
+    paths = {k: obs.counters().get(k, 0) - before.get(k, 0)
+             for k in ("full_agg:resident", "full_agg:xla")}
     backend = trainer.backend
     mesh_devices = int(backend.mesh.devices.size)
     print(f"[sharded] n_clients={n_chips} mesh={dict(backend.mesh.shape)} "
@@ -233,6 +322,13 @@ def phase_sharded(n_chips: int):
     print(f"[sharded] audited collectives={len(backend.collectives)} "
           f"bytes_per_round={backend.bytes_per_round} "
           f"comm_bytes={result.comm_bytes} val_acc={result.val_acc:.4f}")
+    # XLA cannot split the resident kernel over the mesh: the exact eval,
+    # split by party over the chips, keeps XLA's gather
+    print(f"[sharded] eval layers resident={paths['full_agg:resident']} "
+          f"xla={paths['full_agg:xla']}")
+    if paths["full_agg:resident"] or not paths["full_agg:xla"]:
+        _fail(f"split exact eval did not take XLA's aggregation: {paths}")
+    _compare_eval(trainer)
     with jax.default_device(jax.devices()[0]):
         vm_trainer, _, vmapped = _run(cfg.with_(backend="vmapped"))
     if backend.bytes_per_round <= 0 or \

@@ -26,10 +26,11 @@ from typing import Any, Dict, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..comm import compression
 from ..comm.compression import CompressionConfig, Compressor
 from ..graph.sampler import SampledBatch
-from ..models.gnn import BACKBONES
+from ..models.gnn import BACKBONES, MASKED_MEAN
 from ..optim import optimizers as opt_lib
 
 
@@ -1214,6 +1215,15 @@ def full_forward(params, cfg: GlasuConfig, feats, nbr_idx, nbr_mask,
     the previous clamped-dynamic-slice concatenation silently re-read
     earlier rows in that case.
 
+    For a masked-mean backbone (``models.gnn.MASKED_MEAN``) without
+    ``use_pallas``, a layer whose source fits the TPU core's VMEM
+    (``kernels.ops.resident_fits``: false off a TPU), on operands that sit
+    on one device (``kernels.ops.on_one_device``: XLA cannot split the
+    kernel over a mesh), instead takes its neighbour mean for all N nodes
+    of every party from one ``resident_masked_mean`` kernel, and runs the
+    layer's update on all N rows at once. Each layer traced counts
+    ``full_agg:resident`` or ``full_agg:xla`` in ``repro.obs``.
+
     ``collect_agg=True`` additionally returns the post-aggregation stacks
     ``{l: (M, N, h_agg)}`` per aggregation layer — the serving cache's
     warm-fill source. Pad rows are sliced off BEFORE ``_aggregate`` runs
@@ -1221,11 +1231,13 @@ def full_forward(params, cfg: GlasuConfig, feats, nbr_idx, nbr_mask,
     nodes regardless of whether ``chunk`` divides N; the hot-node cache
     can never be poisoned by chunk padding.
     """
+    from ..kernels import ops as kops
     m, n = feats.shape[0], feats.shape[1]
     pad = (-n) % chunk
+    idx_p, mask_p = nbr_idx, nbr_mask
     if pad:
-        nbr_idx = jnp.pad(nbr_idx, ((0, 0), (0, pad), (0, 0)))
-        nbr_mask = jnp.pad(nbr_mask, ((0, 0), (0, pad), (0, 0)))
+        idx_p = jnp.pad(nbr_idx, ((0, 0), (0, pad), (0, 0)))
+        mask_p = jnp.pad(nbr_mask, ((0, 0), (0, pad), (0, 0)))
     n_pad = n + pad
     h = jax.vmap(lambda p, x: x @ p["W"] + p["b"])(params["inp"], feats)
     h0 = h
@@ -1234,18 +1246,29 @@ def full_forward(params, cfg: GlasuConfig, feats, nbr_idx, nbr_mask,
         layer = _client_layer(cfg, l)
 
         def chunk_fn(lo, h_full=h, h0_full=h0, l=l, layer=layer):
-            idx = jax.lax.dynamic_slice_in_dim(nbr_idx, lo, chunk, axis=1)
-            mask = jax.lax.dynamic_slice_in_dim(nbr_mask, lo, chunk, axis=1)
+            idx = jax.lax.dynamic_slice_in_dim(idx_p, lo, chunk, axis=1)
+            mask = jax.lax.dynamic_slice_in_dim(mask_p, lo, chunk, axis=1)
             return jax.vmap(layer)(params["layers"][l], h_full, h0_full,
                                    idx, mask)
 
-        if n_pad == chunk:
-            h_plus = chunk_fn(0)[:, :n]
+        if (cfg.backbone in MASKED_MEAN and not cfg.use_pallas
+                and kops.resident_fits(n, h.shape[-1], nbr_idx.shape[-1])
+                and kops.on_one_device(h, nbr_idx, nbr_mask)):
+            obs.count("full_agg:resident")
+            agg = kops.resident_masked_mean(h, nbr_idx, nbr_mask)
+            h_plus = jax.vmap(
+                lambda p, x, x0, i, k, a, layer=layer: layer(
+                    p, x, x0, i, k, agg=a))(
+                params["layers"][l], h, h0, nbr_idx, nbr_mask, agg)
         else:
-            starts = jnp.arange(0, n_pad, chunk)
-            pieces = jax.lax.map(chunk_fn, starts)   # (C, M, chunk, h)
-            h_plus = jnp.moveaxis(pieces, 0, 1).reshape(
-                m, n_pad, pieces.shape[-1])[:, :n]
+            obs.count("full_agg:xla")
+            if n_pad == chunk:
+                h_plus = chunk_fn(0)[:, :n]
+            else:
+                starts = jnp.arange(0, n_pad, chunk)
+                pieces = jax.lax.map(chunk_fn, starts)   # (C, M, chunk, h)
+                h_plus = jnp.moveaxis(pieces, 0, 1).reshape(
+                    m, n_pad, pieces.shape[-1])[:, :n]
         if l in cfg.agg_layers:
             h, _ = _aggregate(cfg, h_plus)
             if collect_agg:

@@ -32,6 +32,19 @@ and d <= 512 the kernel compiles for v5e (tests/test_tpu_compile.py); the
 v5e compiler runs out of VMEM at n_src = 8192 with d = 512, so full-graph
 sweeps at that scale need narrow rows or the CSR path below.
 
+The exact full-graph pass's neighbour mean (``resident_masked_mean_pallas``,
+last section) keeps a party's whole source in VMEM instead: packed two
+64-wide nodes to a 128-lane row, Reddit's 232,965 nodes take 59.6 MB of the
+v5e core's 128 MiB, in one buffer loaded once per party and layer; the
+tables stream through SMEM. ``kernels.ops.resident_fits`` allows it only
+where the width divides 128 and the packed source, two output blocks and a
+16 MiB reserve fit the VMEM that ``pltpu.get_tpu_info`` reports: on a v5e
+up to 456,704 nodes 64 wide, 228,352 nodes 128 wide. Off a TPU, above
+that, for GAT, under ``use_pallas`` and where the pass's operands span
+several devices (XLA cannot split a Mosaic kernel: ``on_one_device`` in
+``kernels.ops``) the pass keeps XLA's chunked row gather
+(``core.glasu.full_forward``).
+
 Every entry point takes ``interpret`` without a default: ``kernels.ops``
 decides it from the backend, and a direct caller must say which it wants.
 """
@@ -388,3 +401,180 @@ def gat_layer_pallas(h, idx, mask, w, a_src, a_dst, b, *, interpret: bool):
     )(idx, mask, h, w2, a_src2, a_dst2, b2)
     out = out.reshape(idx.shape[0], n_heads, dhp)[:n_dst, :, :dh]
     return out.reshape(n_dst, n_heads * dh)
+
+
+# ------------------------------------------------------ resident masked mean
+# The exact full-graph pass aggregates every node of a party from the same
+# (N, d) source. XLA runs that as a row gather from HBM, which costs a fixed
+# time per row however narrow the row. Here the party's whole source is
+# copied into VMEM once, and each neighbour costs one row load from VMEM.
+# A 64-wide row would take a whole 128-lane row in VMEM's (8, 128) tiling,
+# so the source is packed ``LANE // d`` nodes to a row: node i sits in row
+# i % R, lane group i // R, for R rows rounded up to whole tiles. The
+# kernel's tables carry (i % R) << 7 | the lane roll that brings that
+# group to lanes 0..d-1.
+#
+# The sum keeps the order of XLA's TPU reduce of ``gather_mean``'s
+# (n, F, d) products, whose F axis lies on the 8 sublanes: eight strided
+# accumulators (neighbour f goes to f % 8, in turn), then a halving tree
+# over them (0+4, 1+5, 2+6, 3+7; then +2; then +1). The division by the
+# clamped mask sum runs in XLA, as ``gather_mean``'s does. That order was
+# read off XLA's output on a v5e with JAX 0.9; no contract promises it.
+# There the mean came out bitwise ``gather_mean``'s at F = 5, 12 and 33
+# (``chip_smoke.py``), and a Reddit pass's logits bitwise the XLA path's;
+# another chip or compiler may sum otherwise. A summation order that
+# differs by one rounding flips the one-pass bf16 rounding of ``z @ W``
+# operands and moves a logit by up to 0.7% of the logits' RMS.
+
+RESIDENT_BLOCK = 512             # destinations per grid step
+SUBLANE = 8
+SMEM_TILE = 1024                 # words: the tiling of a 1-D SMEM block
+ROLL_BITS = 7                    # a table entry is row << 7 | lane roll
+UNROLLED = 4                     # destinations unrolled per loop step
+
+
+def resident_pack(d: int) -> int:
+    """Nodes per packed 128-lane row for source width ``d``, or 0 when the
+    kernel cannot pack that width (it must divide 128)."""
+    return LANE // d if 0 < d <= LANE and LANE % d == 0 else 0
+
+
+def _resident_rows(n_src: int, pack: int) -> int:
+    """Packed rows R, rounded up to whole (8, 128) tiles."""
+    rows = -(-n_src // pack)
+    return -(-rows // SUBLANE) * SUBLANE
+
+
+def resident_vmem_bytes(n_src: int, d: int) -> int:
+    """VMEM the kernel asks for: the packed source, single-buffered, and
+    the two buffers of its (block, d) output, lane-padded."""
+    packed = _resident_rows(n_src, resident_pack(d)) * LANE * 4
+    return packed + 2 * RESIDENT_BLOCK * LANE * 4
+
+
+def _table_stride(block: int, fanout: int) -> int:
+    """Words per block of a 1-D SMEM table, padded to the 1-D tiling (a
+    block of a 2-D SMEM array would need 8 rows)."""
+    return -(-block * fanout // SMEM_TILE) * SMEM_TILE
+
+
+def resident_smem_bytes(fanout: int) -> int:
+    """SMEM the kernel's tables take: slots and mask, two buffers each."""
+    return 4 * _table_stride(RESIDENT_BLOCK, fanout) * 4
+
+
+def _halving_sum(parts):
+    """Sum eight sublane partials as a halving tree. Absent ones (None,
+    past a fanout under 8) add nothing, as XLA's zero padding adds 0."""
+    k = len(parts) // 2
+    while k:
+        for i in range(k):  # glint: disable=GL004 static 7-add tree
+            if parts[i + k] is not None:
+                parts[i] = parts[i] + parts[i + k]
+        k //= 2
+    return parts[0]
+
+
+def _resident_kernel(q_ref, w_ref, src, out_ref, *, fanout, pack, d):
+    """One (party, destination block) program: masked sums, not means.
+
+    ``src`` is the party's packed source, the same block for all of the
+    party's programs, so it is copied into VMEM once per party.
+    Destinations go eight at a time, one output tile, four to a loop step
+    (on a v5e as fast as eight, and half the code to trace and compile):
+    each neighbour's packed row is loaded, rolled by a dynamic amount that
+    brings its lane group to lanes 0..d-1, scaled by its mask, and summed
+    in XLA's order (section comment).
+    """
+    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, LANE), 0)
+
+    def neighbour(at):
+        q = q_ref[at]
+        x = src[pl.ds(q >> ROLL_BITS, 1), :]               # (1, 128)
+        if pack > 1:    # its lane group to lanes 0..d-1
+            x = pltpu.roll(x, q & (LANE - 1), 1)
+        return x * w_ref[at]
+
+    def rows(g, step, block):
+        """Destinations 4·step .. 4·step + 3 of group g into ``block``."""
+        first = g * (SUBLANE * fanout) + step * (UNROLLED * fanout)
+        for r in range(UNROLLED):  # glint: disable=GL004 static 4-row unroll
+            parts = [None] * SUBLANE
+            for f in range(fanout):  # glint: disable=GL004 static fanout unroll at trace time
+                x = neighbour(first + (r * fanout + f))
+                k = f % SUBLANE
+                parts[k] = x if parts[k] is None else parts[k] + x
+            block = jnp.where(sub == step * UNROLLED + r, _halving_sum(parts),
+                              block)
+        return block
+
+    def group(g, carry):
+        block = jax.lax.fori_loop(0, SUBLANE // UNROLLED,
+                                  functools.partial(rows, g),
+                                  jnp.zeros((SUBLANE, LANE), jnp.float32))
+        out_ref[pl.ds(pl.multiple_of(g * SUBLANE, SUBLANE), SUBLANE), :] = \
+            block[:, :d]
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0] // SUBLANE, group, 0)
+
+
+def resident_masked_mean_pallas(h, idx, mask, *, interpret: bool,
+                                vmem_limit_bytes: int):
+    """Masked mean of each destination's neighbour rows, source in VMEM.
+
+    h: (M, n_src, d) party sources; idx/mask: (M, n_dst, F) neighbour
+    tables -> (M, n_dst, d) float32: ``models.gnn.gather_mean`` per party,
+    in the summation order XLA used for it on a v5e (section comment).
+    ``d`` must divide 128 (``resident_pack``). Indices are clipped into
+    [0, n_src) as XLA's gather clips them; pad entries are idx 0 under
+    mask 0.
+    Grid is (parties, destination blocks). The source block changes only
+    with the party, so it is fetched once per party, into one VMEM buffer
+    (``pl.Buffered(1)``); the tables stream through SMEM a block at a
+    time.
+    """
+    m, n_src, d = h.shape
+    n_dst, fanout = idx.shape[1:]
+    pack = resident_pack(d)
+    if not pack:
+        raise ValueError(f"source width {d} does not divide {LANE}")
+    rows = _resident_rows(n_src, pack)
+    hp = jnp.pad(h.astype(jnp.float32),
+                 ((0, 0), (0, rows * pack - n_src), (0, 0)))
+    src = jnp.swapaxes(hp.reshape(m, pack, rows, d), 1, 2).reshape(
+        m, rows, LANE)
+    block = min(RESIDENT_BLOCK, -(-n_dst // DST_BLOCK) * DST_BLOCK)
+    pad = (-n_dst) % block
+    idx = jnp.clip(idx.astype(jnp.int32), 0, n_src - 1)
+    roll = (idx // rows * (LANE - d)) % LANE       # lane group to lanes 0..d-1
+    q = jnp.pad((idx % rows) << ROLL_BITS | roll, ((0, 0), (0, pad), (0, 0)))
+    w = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)))
+    n_blocks = (n_dst + pad) // block
+    stride = _table_stride(block, fanout)
+
+    def table(x):
+        x = x.reshape(m * n_blocks, block * fanout)
+        return jnp.pad(x, ((0, 0), (0, stride - block * fanout))).reshape(-1)
+
+    spec = pl.BlockSpec((stride,), lambda p, j: (p * n_blocks + j,),
+                        memory_space=pltpu.SMEM)
+    sums = pl.pallas_call(
+        functools.partial(_resident_kernel, fanout=fanout, pack=pack, d=d),
+        name="resident_masked_mean",
+        grid=(m, n_blocks),
+        in_specs=[
+            spec,                                            # slots
+            spec,                                            # mask
+            pl.BlockSpec((None, rows, LANE), lambda p, j: (p, 0, 0),
+                         memory_space=pltpu.VMEM,
+                         pipeline_mode=pl.Buffered(1)),      # packed source
+        ],
+        out_specs=pl.BlockSpec((None, block, d), lambda p, j: (p, j, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((m, n_dst + pad, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
+        interpret=interpret,
+    )(table(q), table(w), src)[:, :n_dst]
+    return sums / jnp.maximum(jnp.sum(mask, axis=-1, keepdims=True), 1.0)

@@ -18,12 +18,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from ..graph.csr_plan import csr_slot_map, plan_csr_slabs
 from . import ref
 from .flash_attention import flash_attention_pallas
 from .graph_agg import (ell_to_slabs, gat_layer_pallas, gcnii_layer_pallas,
-                        graph_agg_csr_pallas, graph_agg_pallas)
+                        graph_agg_csr_pallas, graph_agg_pallas,
+                        resident_masked_mean_pallas, resident_pack,
+                        resident_smem_bytes, resident_vmem_bytes)
 
 
 def _interpret() -> bool:
@@ -206,3 +209,53 @@ def gcnii_layer(h, h0, idx, mask, w, b, alpha: float, beta: float):
 def gat_layer(h, idx, mask, w, a_src, a_dst, b):
     """Fused multi-head GAT sub-layer: projection + masked attention + mix."""
     return _gat_layer(h, idx, mask, w, a_src, a_dst, b)
+
+
+# ------------------------------------------------- resident masked mean
+# VMEM left to Mosaic's own scratch beside the resident source
+RESIDENT_VMEM_RESERVE = 16 * 1024 * 1024
+
+
+def _tpu_memory():
+    """(VMEM, SMEM) bytes per core of the attached TPU, or (0, 0) off one."""
+    if not pltpu.is_tpu_device():
+        return 0, 0
+    info = pltpu.get_tpu_info()
+    return info.vmem_capacity_bytes, info.smem_capacity_bytes
+
+
+def resident_fits(n_src: int, d: int, fanout: int) -> bool:
+    """Whether ``resident_masked_mean`` can hold an (n_src, d) source in
+    this device's VMEM, beside a reserve, and its (destination block,
+    fanout) tables in half its SMEM (the rest is Mosaic's). False off a
+    TPU and for widths that do not divide 128."""
+    vmem, smem = _tpu_memory()
+    return (bool(resident_pack(d))
+            and resident_vmem_bytes(n_src, d) + RESIDENT_VMEM_RESERVE <= vmem
+            and resident_smem_bytes(fanout) <= smem // 2)
+
+
+def on_one_device(*xs) -> bool:
+    """Whether no operand's type carries a mesh of more than one device.
+
+    XLA's partitioner cannot split a Mosaic kernel, so a pass whose
+    operands may span devices (the sharded backend's exact eval, a
+    session holding its params) keeps XLA's gather. The type's mesh is
+    the only placement a trace sees. It outlives a move of the array to
+    one device, which then takes XLA too; and it is empty for operands
+    that a caller's ``jax.jit(in_shardings=...)`` splits only as it
+    lowers, where JAX refuses the kernel rather than run it wrong."""
+    return all(jax.typeof(x).sharding.mesh.size <= 1 for x in xs)
+
+
+@jax.jit
+def resident_masked_mean(h, idx, mask):
+    """Masked-mean aggregation of stacked parties, each party's source held
+    in VMEM: h (M, n_src, d), idx/mask (M, n_dst, F) -> (M, n_dst, d),
+    ``models.gnn.gather_mean`` per party, in the summation order XLA uses
+    for it on a v5e (``kernels/graph_agg.py``). The exact full-graph pass
+    calls it where ``resident_fits`` and ``on_one_device``; it has no
+    gradient rule (the pass is eval only). Jitted, so the layers of one
+    pass share one trace and lowering of the kernel."""
+    return resident_masked_mean_pallas(h, idx, mask, interpret=_interpret(),
+                                       vmem_limit_bytes=_tpu_memory()[0])

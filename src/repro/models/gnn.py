@@ -37,8 +37,11 @@ def init_gcn_layer(key, d_in, d_out):
             "b": jnp.zeros((d_out,))}
 
 
-def gcn_layer(p, h, h0, idx, mask):
-    agg = gather_mean(h, idx, mask)
+def gcn_layer(p, h, h0, idx, mask, agg=None):
+    """``agg``, when given, is ``gather_mean(h, idx, mask)`` computed by the
+    caller (the full-graph pass computes it in one kernel for all nodes)."""
+    if agg is None:
+        agg = gather_mean(h, idx, mask)
     return jax.nn.relu(agg @ p["W"] + p["b"])
 
 
@@ -47,9 +50,12 @@ def init_gcnii_layer(key, d_in, d_out):
     return init_gcn_layer(key, d_in, d_out)
 
 
-def gcnii_layer(p, h, h0, idx, mask, alpha: float = 0.1, beta: float = 0.5):
-    """GCNII: initial-residual + identity-mapping skip connections."""
-    agg = gather_mean(h, idx, mask)
+def gcnii_layer(p, h, h0, idx, mask, alpha: float = 0.1, beta: float = 0.5,
+                agg=None):
+    """GCNII: initial-residual + identity-mapping skip connections.
+    ``agg`` as for ``gcn_layer``."""
+    if agg is None:
+        agg = gather_mean(h, idx, mask)
     z = (1.0 - alpha) * agg + alpha * h0[idx[:, 0]]  # h0 at the output node set
     return jax.nn.relu((1.0 - beta) * z + beta * (z @ p["W"]) + p["b"])
 
@@ -80,6 +86,10 @@ def gat_layer(p, h, h0, idx, mask):
     out = out.reshape(out.shape[0], n_heads * dh)
     return jax.nn.elu(out + p["b"])
 
+
+# backbones whose aggregation is ``gather_mean`` and whose layer takes it
+# precomputed as ``agg``
+MASKED_MEAN = frozenset({"gcn", "gcnii"})
 
 BACKBONES = {
     "gcn": (init_gcn_layer, gcn_layer),

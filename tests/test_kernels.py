@@ -9,7 +9,9 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ops
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.graph_agg import graph_agg_pallas
+from repro.kernels.graph_agg import (graph_agg_pallas,
+                                     resident_masked_mean_pallas)
+from repro.models.gnn import gather_mean
 
 
 # ------------------------------------------------------------------ graph_agg
@@ -32,6 +34,64 @@ def test_graph_agg_matches_ref(n_src, n_dst, fanout, d, d_out, dtype):
     want = ref.graph_agg_ref(h, idx, mask, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------ resident masked mean
+@pytest.mark.parametrize("m,n_src,n_dst,fanout,d", [
+    (3, 301, 301, 5, 64),       # odd N: the packing's pad row; ragged block
+    (3, 513, 513, 4, 64),       # one past a 512-node block
+    (1, 700, 130, 6, 64),       # fewer destinations than sources
+    (2, 257, 200, 3, 32),       # four nodes to a packed row
+    (2, 100, 90, 4, 128),       # one node to a row
+])
+def test_resident_masked_mean_matches_gather_mean(m, n_src, n_dst, fanout,
+                                                  d):
+    """The resident kernel is ``gather_mean`` over the stacked parties up
+    to summation order, and bitwise against a model of the order the
+    kernel keeps (the one XLA's reduce used on a v5e): float masks,
+    all-zero rows, pad entries at node 0 and neighbours in every lane
+    group of the packed rows."""
+    rng = np.random.default_rng(n_src)
+    h = jnp.asarray(rng.normal(size=(m, n_src, d)), jnp.float32)
+    idx = rng.integers(0, n_src, size=(m, n_dst, fanout)).astype(np.int32)
+    # float weights whose products are exact, so that no fused multiply-add
+    # can move the bitwise check below
+    mask = rng.choice(np.float32([0.0, 0.25, 0.5, 1.0]), (m, n_dst, fanout))
+    mask[:, ::5, 1:] = 0.0                  # pad entries at node 0
+    idx[:, ::5, 1:] = 0
+    mask[:, 3::11] = 0.0                    # rows with no neighbour
+    pack = 128 // d
+    rows = -(-n_src // pack)
+    rows += (-rows) % 8                     # packed rows, whole tiles
+    assert set(np.unique(idx[mask > 0] // rows)) == set(range(pack)), \
+        "every lane group holds some neighbour"
+    idx, mask = jnp.asarray(idx), jnp.asarray(mask)
+    got = resident_masked_mean_pallas(h, idx, mask, interpret=True,
+                                      vmem_limit_bytes=0)
+    want = jax.vmap(gather_mean)(h, idx, mask)
+    assert got.shape == want.shape == (m, n_dst, d)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=1e-6 * scale)
+    np.testing.assert_array_equal(np.asarray(got)[:, 3::11], 0.0)
+    # bitwise, in the order the kernel keeps: XLA's on a v5e, F on 8 sublanes
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_sublane_order_mean(h, idx, mask)))
+
+
+def _sublane_order_mean(h, idx, mask):
+    """``gather_mean`` summed as XLA's TPU reduce sums an F axis laid on 8
+    sublanes: slot s adds neighbours s, s+8, ... in turn, then the slots
+    add as a halving tree (0+4, 1+5, 2+6, 3+7; +2; +1)."""
+    g = h[jnp.arange(h.shape[0])[:, None, None], idx] * mask[..., None]
+    slots = [g[:, :, s] for s in range(min(8, g.shape[2]))]
+    for f in range(8, g.shape[2]):
+        slots[f % 8] = slots[f % 8] + g[:, :, f]
+    slots += [jnp.zeros_like(slots[0])] * (8 - len(slots))
+    while len(slots) > 1:
+        half = len(slots) // 2
+        slots = [slots[i] + slots[i + half] for i in range(half)]
+    return slots[0] / jnp.maximum(jnp.sum(mask, axis=-1, keepdims=True), 1.0)
 
 
 # --------------------------------------------------- fused backbone kernels

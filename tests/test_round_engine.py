@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.api import ExperimentConfig, Trainer
 from repro.api.trainer import step_schedule
 from repro.core import glasu
@@ -20,6 +21,7 @@ from repro.core.glasu import GlasuConfig
 from repro.graph.prefetch import PrefetchSampler, stack_rounds, unstack_round
 from repro.graph.sampler import GlasuSampler, SamplerConfig
 from repro.graph.synth import make_vfl_dataset
+from repro.kernels import ops as kops
 from repro.optim import optimizers as opt_lib
 
 TINY = dict(name="engine", dataset="tiny", hidden=16, batch_size=8,
@@ -427,6 +429,95 @@ def test_full_forward_chunked_matches_unchunked():
         out = glasu.full_forward(params, cfg, feats, idx, mask, chunk=chunk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                    rtol=1e-5, atol=1e-5)
+
+
+def _full_graph(m=3, n=301, d_in=12, cap=6, seed=0):
+    """Party features and neighbour tables as the full-graph pass takes
+    them: column 0 is the node itself, pad entries are node 0 under mask
+    0, and some rows have no neighbour at all."""
+    rng = np.random.default_rng(seed)
+    feats = jnp.asarray(rng.normal(size=(m, n, d_in)), jnp.float32)
+    idx = rng.integers(0, n, size=(m, n, cap + 1)).astype(np.int32)
+    idx[..., 0] = np.arange(n)[None]
+    mask = (rng.random((m, n, cap + 1)) < 0.8).astype(np.float32)
+    mask[..., 0] = 1.0
+    mask[:, ::7] = 0.0
+    idx = np.where(mask > 0, idx, 0).astype(np.int32)
+    return feats, jnp.asarray(idx), jnp.asarray(mask)
+
+
+def _full_agg_counts(fn):
+    """Run ``fn`` and return the ``full_agg:*`` counts it added."""
+    names = ("full_agg:resident", "full_agg:xla")
+    before = obs.counters()
+    out = fn()
+    after = obs.counters()
+    return out, {k: after.get(k, 0) - before.get(k, 0) for k in names}
+
+
+# the v5e core's VMEM and SMEM (pltpu.get_tpu_info)
+V5E_MEMORY = (128 * 1024 * 1024, 1024 * 1024)
+
+
+@pytest.mark.parametrize("backbone,hidden,agg", [
+    ("gcn", 64, "mean"), ("gcnii", 64, "mean"),
+    ("gcn", 32, "concat"),     # concat widens layer 2 to 96: not packable
+])
+def test_full_forward_resident_matches_xla(monkeypatch, backbone, hidden,
+                                           agg):
+    """The resident-kernel pass (in interpret mode, on a described v5e's
+    memory) gives the XLA pass's logits and collected stacks, layer by
+    layer wherever the source width packs into 128 lanes."""
+    m, n_layers = 3, 4
+    cfg = GlasuConfig(n_clients=m, n_layers=n_layers, hidden=hidden,
+                      n_classes=5, d_in=12, backbone=backbone, agg=agg,
+                      agg_layers=(1, 3))
+    params = glasu.init_params(jax.random.PRNGKey(1), cfg)
+    feats, idx, mask = _full_graph(m)
+
+    def run():      # a fresh jit each time: the path is chosen as it traces
+        return jax.jit(lambda p: glasu.full_forward(
+            p, cfg, feats, idx, mask, chunk=128, collect_agg=True))(params)
+
+    (want, want_aggs), xla = _full_agg_counts(run)
+    assert xla == {"full_agg:resident": 0, "full_agg:xla": n_layers}
+    monkeypatch.setattr(kops, "_tpu_memory", lambda: V5E_MEMORY)
+    (got, got_aggs), res = _full_agg_counts(run)
+    n_wide = 1 if agg == "concat" else 0
+    assert res == {"full_agg:resident": n_layers - n_wide,
+                   "full_agg:xla": n_wide}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert sorted(got_aggs) == sorted(want_aggs) == [1, 3]
+    for l in want_aggs:
+        np.testing.assert_allclose(np.asarray(got_aggs[l]),
+                                   np.asarray(want_aggs[l]),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["gat", "over_budget", "no_tpu",
+                                  "use_pallas"])
+def test_full_forward_dispatch_takes_xla(monkeypatch, case):
+    """GAT, a source larger than VMEM, a CPU backend and ``use_pallas``
+    all keep the XLA chunk loop, whose output is unchanged."""
+    m, n_layers = 3, 4
+    cfg = GlasuConfig(n_clients=m, n_layers=n_layers, hidden=64, n_classes=5,
+                      d_in=12, backbone="gat" if case == "gat" else "gcnii",
+                      agg_layers=(1, 3), use_pallas=case == "use_pallas")
+    params = glasu.init_params(jax.random.PRNGKey(2), cfg)
+    feats, idx, mask = _full_graph(m, n=150)
+    if case == "over_budget":   # VMEM one reserve short of this source
+        monkeypatch.setattr(kops, "_tpu_memory", lambda: (
+            kops.resident_vmem_bytes(150, 64)
+            + kops.RESIDENT_VMEM_RESERVE - 1, V5E_MEMORY[1]))
+    elif case != "no_tpu":
+        monkeypatch.setattr(kops, "_tpu_memory", lambda: V5E_MEMORY)
+    else:
+        assert kops._tpu_memory() == (0, 0)
+    out, counts = _full_agg_counts(lambda: jax.jit(lambda p: glasu.full_forward(
+        p, cfg, feats, idx, mask, chunk=64))(params))
+    assert counts == {"full_agg:resident": 0, "full_agg:xla": n_layers}
+    assert np.isfinite(np.asarray(out)).all()
 
 
 # -------------------------------------------- glint layer-3 runtime guards

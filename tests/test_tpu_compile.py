@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.graph_agg import (ell_to_slabs, gat_layer_pallas,
                                      gcnii_layer_pallas, graph_agg_csr_pallas,
-                                     graph_agg_pallas)
+                                     graph_agg_pallas,
+                                     resident_masked_mean_pallas)
 
 N_SRC, N_DST, FANOUT1, D, M, HEADS = 512, 256, 4, 64, 3, 2
 
@@ -103,3 +104,69 @@ def test_graph_agg_vmem_bound_of_docstring_fits(one_chip):
 
     _compile(_graph_agg, s((512, 512)), s((512, FANOUT1), "int32"),
              s((512, FANOUT1)), s((512, 512)))
+
+
+def test_resident_masked_mean_compiles_at_reddit_shape(one_chip):
+    """The full-graph pass's resident kernel at Reddit's shape: 3 parties'
+    232,965-node sources, 64 wide, tables of 33. The packed source takes
+    59.6 MB of the core's 128 MiB of VMEM (``pltpu.get_tpu_info`` for
+    a v5e), which the kernel is allowed in full."""
+    m, n, f, d = 3, 232_965, 33, 64
+
+    def s(shape, dtype="float32"):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _compile(lambda h, i, k: resident_masked_mean_pallas(
+        h, i, k, interpret=False, vmem_limit_bytes=128 * 1024 * 1024),
+        s((m, n, d)), s((m, n, f), "int32"), s((m, n, f)))
+
+
+@pytest.mark.parametrize("placement", ["split", "one_chip"])
+def test_full_forward_on_four_chips_keeps_kernel_off_the_mesh(
+        topo, monkeypatch, placement):
+    """The sharded backend's exact eval: parameters split by party over a
+    2x2 v5e's four chips (Cora's 2,708 nodes, GCNII 64 wide). XLA's
+    partitioner cannot split a Mosaic kernel, so that pass takes XLA's
+    gather on every layer and compiles; the same pass on one chip of the
+    four takes the resident kernel on every layer."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import obs
+    from repro.core import glasu
+    from repro.kernels import ops as kops
+    from repro.launch import sharding as shd
+    from repro.launch.mesh import make_client_mesh
+
+    monkeypatch.setattr(kops, "_tpu_memory",
+                        lambda: (128 * 1024 * 1024, 1024 * 1024))
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    m, n, f, d_in, n_layers = 4, 2708, 11, 358, 4
+    mesh = make_client_mesh(m, devices=topo.devices)
+    cfg = glasu.GlasuConfig(n_clients=m, n_layers=n_layers, hidden=D,
+                            n_classes=7, d_in=d_in, backbone="gcnii",
+                            agg_layers=(1, 3))
+    params = jax.eval_shape(
+        lambda: glasu.init_params(jax.random.PRNGKey(0), cfg))
+    if placement == "split":
+        param_sh = shd.tree_shardings(shd.client_param_specs(params, mesh),
+                                      mesh)
+        table_sh = NamedSharding(mesh, P())
+    else:
+        param_sh = jax.tree.map(lambda _: SingleDeviceSharding(
+            topo.devices[0]), params)
+        table_sh = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, param_sh)
+    tables = (jax.ShapeDtypeStruct((m, n, d_in), "float32", sharding=table_sh),
+              jax.ShapeDtypeStruct((m, n, f), "int32", sharding=table_sh),
+              jax.ShapeDtypeStruct((m, n, f), "float32", sharding=table_sh))
+    before = obs.counters()
+    text = jax.jit(lambda p, x, i, k: glasu.full_forward(
+        p, cfg, x, i, k, chunk=n)).lower(params, *tables).compile().as_text()
+    paths = {k: obs.counters().get(k, 0) - before.get(k, 0)
+             for k in ("full_agg:resident", "full_agg:xla")}
+    resident = n_layers if placement == "one_chip" else 0
+    assert paths == {"full_agg:resident": resident,
+                     "full_agg:xla": n_layers - resident}
+    assert text.count("tpu_custom_call") == resident

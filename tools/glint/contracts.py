@@ -462,6 +462,18 @@ def _ep_gat_kernel():
     return closed, None
 
 
+def _ep_resident_masked_mean_kernel():
+    import jax
+    from repro.kernels.graph_agg import resident_masked_mean_pallas
+    h = jax.ShapeDtypeStruct((2, 40, 64), "float32")
+    idx = jax.ShapeDtypeStruct((2, 24, 5), "int32")
+    mask = jax.ShapeDtypeStruct((2, 24, 5), "float32")
+    closed = jax.make_jaxpr(
+        functools.partial(resident_masked_mean_pallas, interpret=True,
+                          vmem_limit_bytes=0))(h, idx, mask)
+    return closed, None
+
+
 # name -> (builder, repo-relative path of the code under contract)
 ENTRY_POINTS: Dict[str, Tuple[Callable, str]] = {
     "make_round_fn": (_ep_round_fn, "src/repro/core/glasu.py"),
@@ -491,6 +503,8 @@ ENTRY_POINTS: Dict[str, Tuple[Callable, str]] = {
     "gcnii_layer_pallas": (_ep_gcnii_kernel,
                            "src/repro/kernels/graph_agg.py"),
     "gat_layer_pallas": (_ep_gat_kernel, "src/repro/kernels/graph_agg.py"),
+    "resident_masked_mean_pallas": (_ep_resident_masked_mean_kernel,
+                                    "src/repro/kernels/graph_agg.py"),
     "flash_attention_pallas": (_ep_flash_kernel,
                                "src/repro/kernels/flash_attention.py"),
 }
