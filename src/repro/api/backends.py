@@ -22,6 +22,7 @@ from typing import Any, Optional, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..comm.compression import make_compressor
 from ..core import glasu
 from ..core.glasu import GlasuConfig
@@ -516,38 +517,50 @@ class ShardedBackend:
         return _analytic_bytes(self.cfg, self.sampler, self.compressor,
                                n_uploads=plan.n_present)
 
+    def _placed(self, params, opt_state, batch, round_stacked: bool):
+        """Every mesh placement of a step (params, optimizer state, batch
+        and the compression and fault carries), under the ``place`` span."""
+        with obs.span("place"):
+            params, opt_state = self._place(params, opt_state)
+            batch = self._place_batch(batch, round_stacked)
+            comp_state = self._placed_comp_state()
+            fault_state = None if self.fault_state is None \
+                else self._placed_fault_state()
+        return params, opt_state, batch, comp_state, fault_state
+
     def run_round(self, params, opt_state, batch, key, faults=None):
         _check_fault_args(self.cfg, self.fault_state, faults)
         if self._round_fn is None:
             self._round_fn = glasu.make_sharded_round_fn(
                 self.cfg, self.optimizer, self.mesh)
-        params, opt_state = self._place(params, opt_state)
-        batch = self._place_batch(batch, round_stacked=False)
+        params, opt_state, batch, comp_state, fault_state = self._placed(
+            params, opt_state, batch, round_stacked=False)
         if self.fault_state is not None:
             if self.compressor is not None:
                 (params, opt_state, self.comp_state, self.fault_state,
                  losses) = self._round_fn(
-                    params, opt_state, self._placed_comp_state(),
-                    self._placed_fault_state(), batch, key,
+                    params, opt_state, comp_state, fault_state, batch, key,
                     _round_faults(faults))
             else:
                 params, opt_state, self.fault_state, losses = self._round_fn(
-                    params, opt_state, self._placed_fault_state(), batch, key,
+                    params, opt_state, fault_state, batch, key,
                     _round_faults(faults))
-            return RoundResult(params, opt_state, losses,
-                               self._fault_bytes(faults))
+            comm = self._fault_bytes(faults)
+            obs.count("exchange_bytes", comm)
+            return RoundResult(params, opt_state, losses, comm)
         if self.compressor is None:
             params, opt_state, losses = self._round_fn(params, opt_state,
                                                        batch, key)
         else:
             params, opt_state, self.comp_state, losses = self._round_fn(
-                params, opt_state, self._placed_comp_state(), batch, key)
+                params, opt_state, comp_state, batch, key)
+        obs.count("exchange_bytes", self.bytes_per_round)
         return RoundResult(params, opt_state, losses, self.bytes_per_round)
 
     def run_step(self, params, opt_state, batches, keys, faults=None):
         _check_fault_args(self.cfg, self.fault_state, faults)
-        params, opt_state = self._place(params, opt_state)
-        batches = self._place_batch(batches, round_stacked=True)
+        params, opt_state, batches, comp_state, fault_state = self._placed(
+            params, opt_state, batches, round_stacked=True)
         if self.fault_state is not None:
             present, weight = faults_lib.stack_plans(faults)
             masks = glasu.RoundFaults(jnp.asarray(present),
@@ -555,21 +568,23 @@ class ShardedBackend:
             if self.compressor is not None:
                 (params, opt_state, self.comp_state, self.fault_state,
                  losses) = self.step_fn(
-                    params, opt_state, self._placed_comp_state(),
-                    self._placed_fault_state(), batches, keys, masks)
+                    params, opt_state, comp_state, fault_state, batches,
+                    keys, masks)
             else:
                 params, opt_state, self.fault_state, losses = self.step_fn(
-                    params, opt_state, self._placed_fault_state(), batches,
-                    keys, masks)
+                    params, opt_state, fault_state, batches, keys, masks)
+            per_round = tuple(self._fault_bytes(p) for p in faults)
+            obs.count("exchange_bytes", sum(per_round))
             return StepResult(params, opt_state, losses, self.bytes_per_round,
-                              comm_bytes_rounds=tuple(
-                                  self._fault_bytes(p) for p in faults))
+                              comm_bytes_rounds=per_round)
         if self.compressor is None:
             params, opt_state, losses = self.step_fn(params, opt_state,
                                                      batches, keys)
         else:
             params, opt_state, self.comp_state, losses = self.step_fn(
-                params, opt_state, self._placed_comp_state(), batches, keys)
+                params, opt_state, comp_state, batches, keys)
+        # the audited price of each round's exchange; len(keys) is a shape
+        obs.count("exchange_bytes", self.bytes_per_round * len(keys))
         return StepResult(params, opt_state, losses, self.bytes_per_round)
 
     def joint_logits(self, params, batch, key=None):
