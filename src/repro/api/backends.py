@@ -21,6 +21,7 @@ from typing import Any, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import obs
 from ..comm.compression import make_compressor
@@ -69,6 +70,11 @@ class Backend(Protocol):
     fault-free (all three built-in backends support faults; the flag
     exists for external/older backends written against the run_round-only
     protocol).
+
+    A backend may also define ``stage(host_batch)``: the ``Trainer`` then
+    hands it each step's round-stacked host batch and dispatches what it
+    returns, in place of its own ``jax.device_put`` onto the default
+    device (``ShardedBackend`` puts it straight onto its client mesh).
     """
 
     name: str
@@ -361,6 +367,30 @@ class SimulationBackend:
         return logits
 
 
+def _placed_as(x, sharding) -> bool:
+    """``x`` is a committed jax array laid out as ``sharding`` says, however
+    the spec is spelled (a step's ``P('clients',)`` output is placed as a
+    ``P('clients', None)`` target, though the two do not compare equal)."""
+    return (isinstance(x, jax.Array) and x.committed
+            and x.sharding.is_equivalent_to(sharding, x.ndim))
+
+
+def _put(tree, shardings):
+    """``tree`` on ``shardings``: a leaf already placed so passes through as
+    the very same array, the others go in one batched ``jax.device_put``.
+    Returns the tree and how many leaves moved and were kept."""
+    leaves, treedef = jax.tree.flatten(tree)
+    targets = treedef.flatten_up_to(shardings)
+    move = [i for i, (x, sh) in enumerate(zip(leaves, targets))
+            if not _placed_as(x, sh)]
+    if move:
+        moved = jax.device_put([leaves[i] for i in move],
+                               [targets[i] for i in move])
+        for i, x in zip(move, moved):
+            leaves[i] = x
+    return treedef.unflatten(leaves), len(move), len(leaves) - len(move)
+
+
 class ShardedBackend:
     """Device-sharded client parallelism over a ``('clients',)`` mesh.
 
@@ -465,6 +495,8 @@ class ShardedBackend:
             model_cfg, optimizer, self.mesh)
         self._round_fn = None
         self._joint_fn = None
+        self._batch_shs = {}
+        self._host_memory = self.mesh.devices.flat[0].platform == "cpu"
 
     def _audited_bytes(self, shell: SampledBatch) -> int:
         """Collective meter vs message log, or raise. Returns bytes/round."""
@@ -486,26 +518,33 @@ class ShardedBackend:
         # traffic comes from the same message log, not the collectives
         return measured + log.total_bytes("index_sync")
 
-    def _place(self, params, opt_state):
-        return (jax.device_put(params, self.param_sh),
-                jax.device_put(opt_state, self.opt_sh))
+    def _batch_sh(self, batch, round_stacked: bool):
+        """The client shardings of a batch (``client_batch_specs``), built
+        once per tree structure, leaf shapes and ``round_stacked``."""
+        leaves, treedef = jax.tree.flatten(batch)
+        key = (treedef, tuple(np.shape(x) for x in leaves), round_stacked)
+        sh = self._batch_shs.get(key)
+        if sh is None:
+            from ..launch import sharding as shd
+            sh = self._batch_shs[key] = shd.tree_shardings(
+                shd.client_batch_specs(batch, self.mesh,
+                                       round_stacked=round_stacked),
+                self.mesh)
+        return sh
 
-    def _place_batch(self, batch, round_stacked: bool):
-        from ..launch import sharding as shd
-        specs = shd.client_batch_specs(batch, self.mesh,
-                                       round_stacked=round_stacked)
-        return jax.device_put(batch, shd.tree_shardings(specs, self.mesh))
+    def stage(self, host_batch):
+        """The Trainer's hand-off of a step's round-stacked host batch: one
+        batched ``jax.device_put`` from the host straight onto the client
+        shardings, so the batch never lands on one chip first.
 
-    def _placed_comp_state(self):
-        """EF carry on-mesh: uplink block sharded, downlink replicated.
-        (No-op after the first step — outputs already carry the sharding.)"""
-        if not self.comp_state:          # None (off) or {} (stateless codec)
-            return self.comp_state
-        return jax.device_put(self.comp_state, self.comp_sh)
-
-    def _placed_fault_state(self):
-        """Stale-cache carry on-mesh: every per-layer stack client-sharded."""
-        return jax.device_put(self.fault_state, self.fault_sh)
+        The result must not alias the host buffers, which the prefetch
+        worker refills once the step is retired. A CPU device's memory is
+        the host's, and its client aliases an aligned numpy buffer instead
+        of copying it, so there the batch is copied first."""
+        if self._host_memory:
+            host_batch = jax.tree.map(np.copy, host_batch)
+        return jax.device_put(host_batch,
+                              self._batch_sh(host_batch, round_stacked=True))
 
     def _fault_bytes(self, plan) -> int:
         """Delivered-only price of one fault round on the federated wire.
@@ -519,14 +558,20 @@ class ShardedBackend:
 
     def _placed(self, params, opt_state, batch, round_stacked: bool):
         """Every mesh placement of a step (params, optimizer state, batch
-        and the compression and fault carries), under the ``place`` span."""
+        and the compression and fault carries), under the ``place`` span.
+        A step's own outputs and a staged batch pass through; what arrives
+        from off the mesh (init, a restore, a host batch) moves. Counts the
+        leaves in ``place_moved`` and ``place_kept``."""
         with obs.span("place"):
-            params, opt_state = self._place(params, opt_state)
-            batch = self._place_batch(batch, round_stacked)
-            comp_state = self._placed_comp_state()
-            fault_state = None if self.fault_state is None \
-                else self._placed_fault_state()
-        return params, opt_state, batch, comp_state, fault_state
+            placed, moved, kept = _put(
+                (params, opt_state, batch, self.comp_state,
+                 self.fault_state),
+                (self.param_sh, self.opt_sh,
+                 self._batch_sh(batch, round_stacked), self.comp_sh,
+                 self.fault_sh))
+            obs.count("place_moved", moved)
+            obs.count("place_kept", kept)
+        return placed
 
     def run_round(self, params, opt_state, batch, key, faults=None):
         _check_fault_args(self.cfg, self.fault_state, faults)
@@ -590,8 +635,9 @@ class ShardedBackend:
     def joint_logits(self, params, batch, key=None):
         if self._joint_fn is None:
             self._joint_fn = glasu.make_sharded_joint_fn(self.cfg, self.mesh)
-        params = jax.device_put(params, self.param_sh)
-        batch = self._place_batch(batch, round_stacked=False)
+        (params, batch), _, _ = _put(
+            (params, batch),
+            (self.param_sh, self._batch_sh(batch, round_stacked=False)))
         return self._joint_fn(params, batch, key)
 
 

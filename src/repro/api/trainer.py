@@ -516,6 +516,8 @@ class Trainer:
         prefetch = PrefetchSampler(self.sampler, schedule,
                                    n_buffers=cfg.prefetch_buffers,
                                    start=st.round) if schedule else None
+        # a backend's own staging hand-off (the sharded mesh), if it has one
+        stage = getattr(self.backend, "stage", None)
         try:
             t = st.round
             for _ in schedule:
@@ -528,7 +530,8 @@ class Trainer:
                     k = step.rounds
                     with obs.span("stage"):
                         keys = _fold_keys(key, jnp.arange(t, t + k))
-                        batches = jax.device_put(step.data)
+                        batches = jax.device_put(step.data) \
+                            if stage is None else stage(step.data)
                         plans = self.fault_sched.draw_step(k) \
                             if self.fault_sched is not None else None
                         obs.count("stage_bytes", sum(

@@ -58,6 +58,20 @@ def spy(self, *a, **kw):
 
 
 backends.ShardedBackend.bind = spy
+per_step = []
+placed = backends.ShardedBackend._placed
+
+
+def placed_spy(self, *a, **kw):
+    before = obs.counters()
+    out = placed(self, *a, **kw)
+    after = obs.counters()
+    per_step.append([after[n] - before.get(n, 0)
+                     for n in ("place_moved", "place_kept")])
+    return out
+
+
+backends.ShardedBackend._placed = placed_spy
 obs.reset()
 args = harness.parse(["--workload", "cora-gcnii-m4.train-sharded",
                       "--seed", "2147483659", "--seconds", "0.2"])
@@ -74,6 +88,8 @@ print(json.dumps({
     "counters": obs.counters(),
     "place_spans": len(spans),
     "place_parents": sorted({s.parent for s in spans}),
+    "place_per_step": per_step,
+    "batch_shardings": len(be._batch_shs),
 }))
 """
 
@@ -113,3 +129,16 @@ def test_place_spans_and_exchange_counter(sharded_run):
     assert sharded_run["bytes_per_round"] > 0
     assert counters["exchange_bytes"] == \
         sharded_run["bytes_per_round"] * rounds
+
+
+def test_only_the_first_step_moves_state(sharded_run):
+    """The benchmark's weights arrive on one chip and the Adam state off
+    the mesh, so the first step moves them; the batch is staged onto the
+    mesh already, and every later step passes all of its leaves through."""
+    (moved, kept), *rest = sharded_run["place_per_step"]
+    assert len(rest) + 1 == sharded_run["counters"]["rounds"]
+    assert moved > 0 and kept > 0
+    assert all(m == 0 and k == moved + kept for m, k in rest)
+    assert sharded_run["counters"]["place_moved"] == moved
+    # staging and every step share one build of the batch shardings
+    assert sharded_run["batch_shardings"] == 1
