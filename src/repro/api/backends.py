@@ -1,14 +1,15 @@
 """Pluggable execution backends: same Trainer, swappable substrate.
 
 ``VmappedBackend`` is the fast path — clients are a stacked leading axis and
-one jitted round function (``core.glasu.make_round_fn``) advances all of them
-at once; communication is *metered* analytically via the sampler's cost
+one jitted scanned step (``core.glasu.make_multi_round_fn``) advances all of
+them at once; communication is *metered* analytically via the sampler's cost
 model. ``SimulationBackend`` replays the identical round as literal
 client/server messages (``fed.simulation``) — the deployment topology of the
 paper's Fig. 1 — and *audits* the analytic meter against the message log
 every round: a divergence raises instead of silently mis-reporting bytes.
 ``ShardedBackend`` places each client (block) on its own mesh device
-(``shard_map`` over a 'clients' axis; ``core.glasu.make_sharded_*``):
+(``shard_map`` over a 'clients' axis;
+``core.glasu.make_sharded_multi_round_fn``):
 client compute is device-local, aggregation is a real cross-device
 collective, and the byte meter is read off the collectives recorded at
 trace time — audited at bind against the message-passing log instead of
@@ -174,10 +175,29 @@ def _analytic_bytes(cfg: GlasuConfig, sampler: GlasuSampler,
     return 0
 
 
-def _round_faults(plan) -> "glasu.RoundFaults":
-    """Device-side masks for one ``RoundPlan``."""
-    return glasu.RoundFaults(present=jnp.asarray(plan.present, jnp.float32),
-                             weight=jnp.asarray(plan.weight, jnp.float32))
+def _step_faults(plans) -> Optional["glasu.RoundFaults"]:
+    """Device-side (K, M) masks for a step's K ``RoundPlan``s; ``None``
+    off the fault path."""
+    if plans is None:
+        return None
+    present, weight = faults_lib.stack_plans(plans)
+    return glasu.RoundFaults(jnp.asarray(present), jnp.asarray(weight))
+
+
+def run_round_as_step(backend, params, opt_state, batch, key,
+                      faults=None) -> RoundResult:
+    """One round as the backend's K = 1 step. The batch, the key and the
+    plan get a leading round axis of 1, and the losses lose it again;
+    ``x[None]`` and ``jnp.squeeze`` keep a device leaf on its device,
+    where ``losses[0]`` would upload its index. params/opt_state are
+    consumed as by ``run_step``."""
+    out = backend.run_step(params, opt_state,
+                           jax.tree.map(lambda x: x[None], batch), key[None],
+                           faults=None if faults is None else [faults])
+    comm = out.comm_bytes_round if faults is None \
+        else out.comm_bytes_rounds[0]
+    return RoundResult(out.params, out.opt_state,
+                       jnp.squeeze(out.losses, 0), comm)
 
 
 def _check_fault_args(cfg: GlasuConfig, fault_state, faults):
@@ -202,9 +222,7 @@ class VmappedBackend:
     donated) through every round/step alongside the optimizer state, and
     the Trainer checkpoints/restores it via the backend attribute. The
     fault-tolerant stale-embedding cache (``self.fault_state``) is owned
-    the same way; composed binds (faults + compression) thread both
-    carries in the unified engine's ``(params, opt_state, comp_state,
-    fault_state, ...)`` order.
+    the same way; the step takes both as one ``glasu.RoundCarry``.
     """
 
     name = "vmapped"
@@ -223,62 +241,24 @@ class VmappedBackend:
         self.bytes_per_round = _analytic_bytes(model_cfg, sampler,
                                                self.compressor)
         self.step_fn = glasu.make_multi_round_fn(model_cfg, optimizer)
-        self._round_fn = None                 # built lazily for run_round
 
     def _fault_bytes(self, plan) -> int:
         """Delivered-only price of one fault round (uplink × n_present)."""
         return _analytic_bytes(self.cfg, self.sampler, self.compressor,
                                n_uploads=plan.n_present)
 
-    def run_round(self, params, opt_state, batch, key, faults=None):
-        _check_fault_args(self.cfg, self.fault_state, faults)
-        if self._round_fn is None:
-            self._round_fn = glasu.make_round_fn(self.cfg, self.optimizer)
-        if self.fault_state is not None:
-            masks = _round_faults(faults)
-            if self.compressor is not None:
-                (params, opt_state, self.comp_state, self.fault_state,
-                 losses) = self._round_fn(params, opt_state, self.comp_state,
-                                          self.fault_state, batch, key,
-                                          masks)
-            else:
-                params, opt_state, self.fault_state, losses = self._round_fn(
-                    params, opt_state, self.fault_state, batch, key, masks)
-            return RoundResult(params, opt_state, losses,
-                               self._fault_bytes(faults))
-        if self.compressor is None:
-            params, opt_state, losses = self._round_fn(params, opt_state,
-                                                       batch, key)
-        else:
-            params, opt_state, self.comp_state, losses = self._round_fn(
-                params, opt_state, self.comp_state, batch, key)
-        return RoundResult(params, opt_state, losses, self.bytes_per_round)
+    run_round = run_round_as_step
 
     def run_step(self, params, opt_state, batches, keys, faults=None):
         _check_fault_args(self.cfg, self.fault_state, faults)
-        if self.fault_state is not None:
-            present, weight = faults_lib.stack_plans(faults)
-            masks = glasu.RoundFaults(jnp.asarray(present),
-                                      jnp.asarray(weight))
-            if self.compressor is not None:
-                (params, opt_state, self.comp_state, self.fault_state,
-                 losses) = self.step_fn(params, opt_state, self.comp_state,
-                                        self.fault_state, batches, keys,
-                                        masks)
-            else:
-                params, opt_state, self.fault_state, losses = self.step_fn(
-                    params, opt_state, self.fault_state, batches, keys,
-                    masks)
-            return StepResult(params, opt_state, losses, self.bytes_per_round,
-                              comm_bytes_rounds=tuple(
-                                  self._fault_bytes(p) for p in faults))
-        if self.compressor is None:
-            params, opt_state, losses = self.step_fn(params, opt_state,
-                                                     batches, keys)
-        else:
-            params, opt_state, self.comp_state, losses = self.step_fn(
-                params, opt_state, self.comp_state, batches, keys)
-        return StepResult(params, opt_state, losses, self.bytes_per_round)
+        params, opt_state, losses, carry = self.step_fn(
+            params, opt_state, batches, keys,
+            glasu.RoundCarry(self.comp_state, self.fault_state),
+            _step_faults(faults))
+        self.comp_state, self.fault_state = carry
+        return StepResult(params, opt_state, losses, self.bytes_per_round,
+                          comm_bytes_rounds=None if faults is None else tuple(
+                              self._fault_bytes(p) for p in faults))
 
     def joint_logits(self, params, batch, key=None):
         logits, _ = glasu.joint_inference(params, batch, self.cfg, key)
@@ -461,39 +441,30 @@ class ShardedBackend:
                 self.mesh)
 
         # byte meter: record the aggregation collectives from an abstract
-        # trace of the round body, then audit them message-by-message.
+        # trace of the step on a one-round shell (the scan traces its round
+        # body once), then audit them message-by-message.
         # Fault-tolerant binds trace with all-present masks: the mesh
         # collective is shape-static (it always ships M blocks), and the
         # full-participation audit pins the meter; per-round fault prices
         # then come from the SAME audited model with n_present uploads.
         shell = sampler.shape_shell_batch()
         records = []
-        trace_fn = glasu.make_sharded_round_fn(
+        trace_fn = glasu.make_sharded_multi_round_fn(
             model_cfg, optimizer, self.mesh, record=records.append,
             jit=False)
-        if self.fault_state is not None:
-            ones = glasu.RoundFaults(jnp.ones(model_cfg.n_clients),
-                                     jnp.ones(model_cfg.n_clients))
-            if self.compressor is not None:
-                jax.eval_shape(trace_fn, params_abs, opt_abs,
-                               self.comp_state, self.fault_state, shell,
-                               jax.random.PRNGKey(0), ones)
-            else:
-                jax.eval_shape(trace_fn, params_abs, opt_abs,
-                               self.fault_state, shell,
-                               jax.random.PRNGKey(0), ones)
-        elif self.compressor is None:
-            jax.eval_shape(trace_fn, params_abs, opt_abs, shell,
-                           jax.random.PRNGKey(0))
-        else:
-            jax.eval_shape(trace_fn, params_abs, opt_abs, self.comp_state,
-                           shell, jax.random.PRNGKey(0))
+        ones = None if self.fault_state is None else glasu.RoundFaults(
+            jnp.ones((1, model_cfg.n_clients)),
+            jnp.ones((1, model_cfg.n_clients)))
+        jax.eval_shape(trace_fn, params_abs, opt_abs,
+                       jax.tree.map(lambda x: x[None], shell),
+                       jax.random.PRNGKey(0)[None],
+                       glasu.RoundCarry(self.comp_state, self.fault_state),
+                       ones)
         self.collectives = tuple(records)
         self.bytes_per_round = self._audited_bytes(shell)
 
         self.step_fn = glasu.make_sharded_multi_round_fn(
             model_cfg, optimizer, self.mesh)
-        self._round_fn = None
         self._joint_fn = None
         self._batch_shs = {}
         self._host_memory = self.mesh.devices.flat[0].platform == "cpu"
@@ -573,61 +544,21 @@ class ShardedBackend:
             obs.count("place_kept", kept)
         return placed
 
-    def run_round(self, params, opt_state, batch, key, faults=None):
-        _check_fault_args(self.cfg, self.fault_state, faults)
-        if self._round_fn is None:
-            self._round_fn = glasu.make_sharded_round_fn(
-                self.cfg, self.optimizer, self.mesh)
-        params, opt_state, batch, comp_state, fault_state = self._placed(
-            params, opt_state, batch, round_stacked=False)
-        if self.fault_state is not None:
-            if self.compressor is not None:
-                (params, opt_state, self.comp_state, self.fault_state,
-                 losses) = self._round_fn(
-                    params, opt_state, comp_state, fault_state, batch, key,
-                    _round_faults(faults))
-            else:
-                params, opt_state, self.fault_state, losses = self._round_fn(
-                    params, opt_state, fault_state, batch, key,
-                    _round_faults(faults))
-            comm = self._fault_bytes(faults)
-            obs.count("exchange_bytes", comm)
-            return RoundResult(params, opt_state, losses, comm)
-        if self.compressor is None:
-            params, opt_state, losses = self._round_fn(params, opt_state,
-                                                       batch, key)
-        else:
-            params, opt_state, self.comp_state, losses = self._round_fn(
-                params, opt_state, comp_state, batch, key)
-        obs.count("exchange_bytes", self.bytes_per_round)
-        return RoundResult(params, opt_state, losses, self.bytes_per_round)
+    run_round = run_round_as_step
 
     def run_step(self, params, opt_state, batches, keys, faults=None):
         _check_fault_args(self.cfg, self.fault_state, faults)
         params, opt_state, batches, comp_state, fault_state = self._placed(
             params, opt_state, batches, round_stacked=True)
-        if self.fault_state is not None:
-            present, weight = faults_lib.stack_plans(faults)
-            masks = glasu.RoundFaults(jnp.asarray(present),
-                                      jnp.asarray(weight))
-            if self.compressor is not None:
-                (params, opt_state, self.comp_state, self.fault_state,
-                 losses) = self.step_fn(
-                    params, opt_state, comp_state, fault_state, batches,
-                    keys, masks)
-            else:
-                params, opt_state, self.fault_state, losses = self.step_fn(
-                    params, opt_state, fault_state, batches, keys, masks)
+        params, opt_state, losses, carry = self.step_fn(
+            params, opt_state, batches, keys,
+            glasu.RoundCarry(comp_state, fault_state), _step_faults(faults))
+        self.comp_state, self.fault_state = carry
+        if faults is not None:
             per_round = tuple(self._fault_bytes(p) for p in faults)
             obs.count("exchange_bytes", sum(per_round))
             return StepResult(params, opt_state, losses, self.bytes_per_round,
                               comm_bytes_rounds=per_round)
-        if self.compressor is None:
-            params, opt_state, losses = self.step_fn(params, opt_state,
-                                                     batches, keys)
-        else:
-            params, opt_state, self.comp_state, losses = self.step_fn(
-                params, opt_state, comp_state, batches, keys)
         # the audited price of each round's exchange; len(keys) is a shape
         obs.count("exchange_bytes", self.bytes_per_round * len(keys))
         return StepResult(params, opt_state, losses, self.bytes_per_round)

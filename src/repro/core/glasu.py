@@ -476,11 +476,19 @@ def _policy(cfg: GlasuConfig, axis_name: Optional[str] = None,
                       fault_tolerant=cfg.fault_tolerant, record=record)
 
 
-def _policy_arity(pol: ExecPolicy):
-    """Which carries the round threads: (error-feedback, fault-cache).
-    Determines the builder signatures — each active carry adds one leading
-    state argument and one result, and faults append a mask argument."""
-    return pol.compressor is not None, pol.fault_tolerant
+class RoundCarry(NamedTuple):
+    """The state a round threads besides params and optimizer state: one
+    member per policy axis that keeps state across rounds. An inactive
+    member is ``None``, an empty pytree — no argument leaf, no result leaf,
+    nothing donated — so every policy shares one round signature.
+
+      * ``comp_state``: the compressed exchange's error-feedback
+        accumulators (``init_comp_state``; ``{}`` for a stateless codec);
+      * ``fault_state``: the fault-tolerant exchange's stale-embedding
+        cache (``init_fault_state``).
+    """
+    comp_state: Any = None
+    fault_state: Any = None
 
 
 def _record_dense(record, l: int, uploads, h_full):
@@ -766,17 +774,15 @@ def local_update_steps(params, opt_state, batch: SampledBatch, stale,
 
 def _round_body(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
                 pol: ExecPolicy, params, opt_state, batch: SampledBatch,
-                key, comp_state=None, fault_state=None,
+                key, carry: RoundCarry,
                 faults: Optional[RoundFaults] = None):
     """One GLASU round (Alg 1 body): JointInference + Q LocalUpdates.
 
-    THE round body — the only one. Every builder (vmapped / sharded ×
-    single / multi-round × any carry combination) instantiates this
-    function with its :class:`ExecPolicy`; there is no second copy to
-    hand-sync. Always returns the full 5-tuple ``(params, opt_state,
-    comp_state, fault_state, losses)`` — inactive carries pass through
-    as given (``None``); the builder callers drop them from the public
-    signatures.
+    THE round body — the only one. Both builders (vmapped / sharded, any
+    carry combination) instantiate this function with their
+    :class:`ExecPolicy`; there is no second copy to hand-sync. Returns
+    ``(params, opt_state, losses, carry)``; the carry's inactive members
+    pass through as given (``None``).
     """
     if pol.sharded and cfg.labels_at_client is not None:
         raise NotImplementedError(
@@ -785,12 +791,12 @@ def _round_body(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
     fault_w = fault_denom = None
     if cfg.agg_layers:
         _, stale, new_comp, new_cache, denom = _joint_inference_engine(
-            params, batch, cfg, pol, key=key, comp_state=comp_state,
-            fault_state=fault_state, faults=faults)
+            params, batch, cfg, pol, key=key, comp_state=carry.comp_state,
+            fault_state=carry.fault_state, faults=faults)
         if pol.compressor is not None:
-            comp_state = new_comp
+            carry = carry._replace(comp_state=new_comp)
         if pol.fault_tolerant:
-            fault_state = new_cache
+            carry = carry._replace(fault_state=new_cache)
             i0 = jax.lax.axis_index(pol.axis_name) * pol.m_loc \
                 if pol.sharded else 0
             fault_w = _slice_block(pol, faults.weight, i0)
@@ -805,134 +811,100 @@ def _round_body(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
         params, opt_state, batch, stale, cfg, optimizer, g_hl=g_hl,
         fault_w=fault_w, fault_denom=fault_denom,
         axis_name=pol.axis_name, m_loc=pol.m_loc)
-    return params, opt_state, comp_state, fault_state, losses
+    return params, opt_state, losses, carry
 
 
-def _round_caller(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
-                  pol: ExecPolicy):
-    """Positional adapter from a policy's public round signature to
-    ``_round_body``. Argument order: ``params, opt_state, [comp_state,]
-    [fault_state,] batch, key[, faults]`` — each active carry adds one
-    state argument and one result (same order), faults append the round's
-    mask argument. This is the single function every builder wraps (jit /
-    shard_map / scan)."""
-    has_c, has_f = _policy_arity(pol)
+def _round_scan(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
+                pol: ExecPolicy):
+    """K rounds of ``_round_body`` under ``lax.scan``, in the round
+    signature both builders wrap (jit / shard_map): ``(params, opt_state,
+    batches, keys, carry, faults) -> (params, opt_state, losses, carry)``.
+    Params, optimizer state and the :class:`RoundCarry` ride in the scan
+    carry; the round-stacked batches, keys and (K, M) fault masks
+    (``None`` off the fault path) ride in the xs; losses come back (K, Q).
+    """
+    def scan(params, opt_state, batches, keys, carry, faults):
+        def body(state, xs):
+            p, s, c = state
+            batch, key, f = xs
+            p, s, losses, c = _round_body(cfg, optimizer, pol, p, s, batch,
+                                          key, c, f)
+            return (p, s, c), losses
 
-    def round_fn(*args):
-        args = list(args)
-        params, opt_state = args.pop(0), args.pop(0)
-        comp_state = args.pop(0) if has_c else None
-        fault_state = args.pop(0) if has_f else None
-        batch, key = args.pop(0), args.pop(0)
-        faults = args.pop(0) if has_f else None
-        p, s, cs, fs, losses = _round_body(
-            cfg, optimizer, pol, params, opt_state, batch, key,
-            comp_state=comp_state, fault_state=fault_state, faults=faults)
-        return (p, s) + ((cs,) if has_c else ()) + \
-            ((fs,) if has_f else ()) + (losses,)
+        (params, opt_state, carry), losses = jax.lax.scan(
+            body, (params, opt_state, carry), (batches, keys, faults))
+        return params, opt_state, losses, carry
 
-    return round_fn
+    return scan
 
 
-def _multi_round_caller(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
-                        pol: ExecPolicy):
-    """K-round scan over ``_round_caller``'s carry layout: active carries
-    ride in the scan carry (donated by the builders), batches/keys and the
-    (K, M) fault-mask stacks ride in the xs."""
-    has_c, has_f = _policy_arity(pol)
-    n_carry = 2 + has_c + has_f
-
-    def step_fn(*args):
-        carry_in = tuple(args[:n_carry])
-        batches, keys = args[n_carry], args[n_carry + 1]
-        xs = (batches, keys) + ((args[n_carry + 2],) if has_f else ())
-
-        def body(carry, xs_t):
-            p, s = carry[0], carry[1]
-            cs = carry[2] if has_c else None
-            fs = carry[2 + has_c] if has_f else None
-            batch, key = xs_t[0], xs_t[1]
-            f = xs_t[2] if has_f else None
-            p, s, cs, fs, losses = _round_body(
-                cfg, optimizer, pol, p, s, batch, key, comp_state=cs,
-                fault_state=fs, faults=f)
-            return (p, s) + ((cs,) if has_c else ()) + \
-                ((fs,) if has_f else ()), losses
-
-        carry_out, losses = jax.lax.scan(body, carry_in, xs)
-        return carry_out + (losses,)             # losses: (K, Q)
+def _step(scan):
+    """``scan`` under the public step signature, where ``carry`` and
+    ``faults`` default to ``None``: a policy with neither takes the leading
+    four arguments only."""
+    def step_fn(params, opt_state, batches, keys, carry=None, faults=None):
+        return scan(params, opt_state, batches, keys,
+                    RoundCarry() if carry is None else carry, faults)
 
     return step_fn
+
+
+# the step's own state, updated in place: params, opt_state and carry
+_DONATED = (0, 1, 4)
 
 
 def _checked(step_fn, rounds_per_step: int, what: str):
     """Reject a batch stack whose leading round axis disagrees with the
     static ``rounds_per_step`` hint loudly instead of silently scanning a
     different number of rounds. ``_jit`` exposes cache introspection."""
-    def checked(*args):
-        batches = next(a for a in args if isinstance(a, SampledBatch))
-        k = batches.labels.shape[0]
+    def checked(*args, **kwargs):
+        k = args[2].labels.shape[0]
         if k != rounds_per_step:
             raise ValueError(
                 f"{what} built for rounds_per_step={rounds_per_step} "
                 f"got a {k}-round batch stack")
-        return step_fn(*args)
+        return step_fn(*args, **kwargs)
 
     checked._jit = step_fn                       # expose cache introspection
     return checked
-
-
-def make_round_fn(cfg: GlasuConfig, optimizer: opt_lib.Optimizer):
-    """One jitted GLASU round; kept for per-round callers (simulation parity
-    probes, unit tests). The training hot path is ``make_multi_round_fn``.
-
-    The signature follows the policy's carry layout (``_round_caller``):
-    the base ``(params, opt_state, batch, key) -> (params, opt_state,
-    losses)``; ``cfg.compression`` threads the error-feedback carry before
-    ``batch``; ``cfg.fault_tolerant`` threads the stale-cache carry there
-    and appends the round's ``RoundFaults`` masks. Both active (composed
-    fault-tolerant compressed rounds): ``(params, opt_state, comp_state,
-    fault_state, batch, key, faults) -> (params, opt_state, comp_state,
-    fault_state, losses)``.
-    """
-    return jax.jit(_round_caller(cfg, optimizer, _policy(cfg)))
 
 
 def make_multi_round_fn(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
                         rounds_per_step: Optional[int] = None):
     """K GLASU rounds in one dispatch: ``lax.scan`` over round-stacked batches.
 
-    ``batches`` is a ``SampledBatch`` whose every leaf carries a leading
-    round axis K (see ``graph.prefetch.stack_batches``) and ``keys`` is the
-    matching (K, 2) stack of per-round PRNG keys. The scan compiles ONE
-    round body regardless of K and replays it K times device-side — one
-    host dispatch per K rounds instead of per round, which is where the
-    per-round Python/runtime overhead of the Trainer loop goes.
+    ``step_fn(params, opt_state, batches, keys, carry=None, faults=None)
+    -> (params, opt_state, losses, carry)``. ``batches`` is a
+    ``SampledBatch`` whose every leaf carries a leading round axis K (see
+    ``graph.prefetch.stack_rounds``) and ``keys`` is the matching (K, 2)
+    stack of per-round PRNG keys. The scan compiles ONE round body
+    regardless of K and replays it K times device-side — one host dispatch
+    per K rounds instead of per round, which is where the per-round
+    Python/runtime overhead of the Trainer loop goes. A single round is the
+    step at K = 1.
 
-    Every carry (params, opt state, and any active sidecar) is donated:
-    the update is in-place at the XLA level, halving parameter-buffer HBM
-    traffic per step. Callers must treat the passed-in trees as consumed
-    (the Trainer immediately rebinds them).
+    ``carry`` is the :class:`RoundCarry` of the policy's active axes:
+    ``cfg.compression`` fills ``comp_state``, ``cfg.fault_tolerant`` fills
+    ``fault_state`` and takes ``faults``, the round-stacked ``RoundFaults``
+    of (K, M) leaves. Off those axes both stay ``None``.
 
-    Returns ``(params, opt_state, ..., losses)`` with losses of shape
-    (K, Q) — per-round rows, so hook cadence semantics (loss reporting,
-    comm metering) are preserved exactly. K is read off the leading axis at
-    trace time; distinct K values retrace (the Trainer cuts its schedule so
-    a run uses one K, plus at most a tail/cadence remainder).
+    params, opt state and the carry are donated: the update is in-place at
+    the XLA level, halving parameter-buffer HBM traffic per step. Callers
+    must treat the passed-in trees as consumed (the Trainer immediately
+    rebinds them).
+
+    losses have shape (K, Q) — per-round rows, so hook cadence semantics
+    (loss reporting, comm metering) are preserved exactly. K is read off
+    the leading axis at trace time; distinct K values retrace (the Trainer
+    cuts its schedule so a run uses one K, plus at most a tail/cadence
+    remainder).
 
     ``rounds_per_step`` is an optional static hint: when given, a batch
     whose leading axis disagrees is rejected loudly instead of silently
     scanning a different number of rounds.
-
-    Carry layout per policy (``_round_caller``): ``cfg.compression`` adds
-    the error-feedback accumulators to the scan carry, ``cfg.fault_tolerant``
-    adds the stale-embedding cache and puts the round-stacked ``RoundFaults``
-    of (K, M) leaves in the scan xs — composed configs thread both.
     """
-    pol = _policy(cfg)
-    has_c, has_f = _policy_arity(pol)
-    step_fn = jax.jit(_multi_round_caller(cfg, optimizer, pol),
-                      donate_argnums=tuple(range(2 + has_c + has_f)))
+    step_fn = jax.jit(_step(_round_scan(cfg, optimizer, _policy(cfg))),
+                      donate_argnums=_DONATED)
     if rounds_per_step is None:
         return step_fn
     return _checked(step_fn, rounds_per_step, "multi-round step")
@@ -981,11 +953,7 @@ def _gather_clients(x, axis_name: str):
 
 
 def sharded_joint_inference(params, batch: SampledBatch, cfg: GlasuConfig,
-                            key=None, *, axis_name: str, m_loc: int,
-                            record=None,
-                            compressor: Optional[Compressor] = None,
-                            comp_state=None, fault_state=None,
-                            faults: Optional[RoundFaults] = None):
+                            key=None, *, axis_name: str, m_loc: int):
     """Alg 3 under shard_map: per-device client blocks, collective Agg.
 
     All array leaves of ``params``/``batch`` carry the LOCAL client block
@@ -995,42 +963,17 @@ def sharded_joint_inference(params, batch: SampledBatch, cfg: GlasuConfig,
     the same values as the vmapped path — then the device keeps its local
     slice of the broadcast aggregate and the Extract (stale) buffers.
 
-    With a ``compressor``, each device ENCODES its local block first and
-    the ``all_gather`` moves the wire payload (int8 codes + scales, fp8,
-    or top-k value/index pairs) — the collective itself shrinks, not just
-    the metered number. Decode, aggregation, and the compressed downlink
-    then run replicated on the gathered payload (``_compressed_aggregate``
-    with the device's global block offset), and the device keeps the local
-    block of the error-feedback carry. Returns a third value (the updated
-    comp state) in that mode.
-
     Returns (local logits (m_loc, S, C), stale {l: (m_loc, n_{l+1}, h_agg)}).
-    ``record``, when given, is called with a ``CollectiveRecord`` per
-    aggregation layer at trace time (the byte meter's measurement hook).
-
-    With ``fault_state``/``faults`` (masks replicated) each device
-    substitutes cached blocks for absent clients BEFORE the gather, then
-    the identical weighted Agg of the vmapped fault path runs on the
-    gathered effective stack; a 3rd return value carries the refreshed
-    cache. The mesh collective still ships M blocks per layer (the program
-    is shape-static); the federated WIRE meter prices only delivered
-    uploads — see ``docs/FAULTS.md``. With compression AND faults composed
-    the return is the engine's full ``(logits, stale, new_comp_state,
-    new_fault_state, denom)`` (the cache then holds the server's decoded
-    view, replicated — see ``_fault_state_specs``).
+    The compressed and fault-tolerant exchanges run in the same engine
+    from the round body: with a compressor the ``all_gather`` moves the
+    encoded wire payload, and with faults each device substitutes cached
+    blocks for absent clients BEFORE the gather (the mesh collective still
+    ships M blocks; the wire meter prices only delivered uploads — see
+    ``docs/FAULTS.md``).
     """
-    pol = ExecPolicy(axis_name=axis_name, m_loc=m_loc,
-                     compressor=compressor,
-                     fault_tolerant=fault_state is not None, record=record)
-    logits, stale, new_comp, new_cache, denom = _joint_inference_engine(
-        params, batch, cfg, pol, key=key, comp_state=comp_state,
-        fault_state=fault_state, faults=faults)
-    if compressor is not None and fault_state is not None:
-        return logits, stale, new_comp, new_cache, denom
-    if compressor is not None:
-        return logits, stale, new_comp
-    if fault_state is not None:
-        return logits, stale, new_cache
+    pol = ExecPolicy(axis_name=axis_name, m_loc=m_loc)
+    logits, stale, _, _, _ = _joint_inference_engine(params, batch, cfg, pol,
+                                                     key=key)
     return logits, stale
 
 
@@ -1081,10 +1024,13 @@ def _comp_state_specs(cfg: GlasuConfig, comp: Optional[Compressor],
                       axis: str):
     """shard_map specs for the error-feedback carry: uplink accumulators
     are client-stacked (sharded over ``axis``), the downlink accumulator is
-    server state (replicated). ``{}`` for stateless codecs."""
+    server state (replicated). Shaped as ``init_comp_state``: ``None``
+    without compression, ``{}`` for stateless codecs."""
     from jax.sharding import PartitionSpec as P
 
-    if comp is None or not comp.error_feedback:
+    if comp is None:
+        return None
+    if not comp.error_feedback:
         return {}
     return {l: {"up": P(axis), "down": P()} for l in cfg.agg_layers}
 
@@ -1106,74 +1052,48 @@ def _fault_state_specs(cfg: GlasuConfig, axis: str,
 
 
 def _round_specs(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
-                 pol: ExecPolicy, axis: str, round_stacked: bool = False):
-    """(in_specs, out_specs) for shard_mapping a policy's round caller —
-    the spec-tree mirror of ``_round_caller``'s argument layout. The PRNG
-    key, the fault masks (single (M,) rows and round-stacked (K, M) alike)
-    and the loss rows are replicated."""
+                 pol: ExecPolicy, axis: str):
+    """(in_specs, out_specs) for shard_mapping the round signature of
+    ``_round_scan``. The carry's specs follow its members: an inactive
+    member is ``None`` and has no spec. The PRNG keys, the (K, M) fault
+    masks and the loss rows are replicated."""
     from jax.sharding import PartitionSpec as P
 
-    has_c, has_f = _policy_arity(pol)
     pspecs, ospecs, bspecs = _sharded_specs(cfg, optimizer, axis,
-                                            round_stacked=round_stacked)
-    in_specs, out_specs = [pspecs, ospecs], [pspecs, ospecs]
-    if has_c:
-        cspecs = _comp_state_specs(cfg, pol.compressor, axis)
-        in_specs.append(cspecs)
-        out_specs.append(cspecs)
-    if has_f:
-        fspecs = _fault_state_specs(cfg, axis, replicated=has_c)
-        in_specs.append(fspecs)
-        out_specs.append(fspecs)
-    in_specs += [bspecs, P()]
-    if has_f:
-        in_specs.append(RoundFaults(present=P(), weight=P()))
-    out_specs.append(P())
-    return tuple(in_specs), tuple(out_specs)
-
-
-def make_sharded_round_fn(cfg: GlasuConfig, optimizer: opt_lib.Optimizer,
-                          mesh, axis: str = "clients", record=None,
-                          jit: bool = True):
-    """One GLASU round with clients sharded over ``mesh``'s ``axis``.
-
-    ``record`` (see ``CollectiveRecord``) observes the aggregation
-    collectives at trace time; ``jit=False`` returns the bare shard_map'd
-    callable, which is what the byte meter abstractly evaluates at bind.
-    The signature follows the policy's carry layout exactly as
-    ``make_round_fn``'s does: ``cfg.compression`` threads the
-    error-feedback carry before ``batch``, ``cfg.fault_tolerant`` threads
-    the stale-cache carry there and appends the round's fault masks —
-    composed configs thread both: ``(params, opt_state, comp_state,
-    fault_state, batch, key, faults)``."""
-    m_loc = _client_axis_check(cfg, mesh, axis)
-    pol = _policy(cfg, axis_name=axis, m_loc=m_loc, record=record)
-    in_specs, out_specs = _round_specs(cfg, optimizer, pol, axis)
-    fn = jax.shard_map(_round_caller(cfg, optimizer, pol), mesh=mesh,
-                       in_specs=in_specs, out_specs=out_specs,
-                       check_vma=False)
-    return jax.jit(fn) if jit else fn
+                                            round_stacked=True)
+    carry = RoundCarry(
+        comp_state=_comp_state_specs(cfg, pol.compressor, axis),
+        fault_state=_fault_state_specs(
+            cfg, axis, replicated=pol.compressor is not None)
+        if pol.fault_tolerant else None)
+    return ((pspecs, ospecs, bspecs, P(), carry, P()),
+            (pspecs, ospecs, P(), carry))
 
 
 def make_sharded_multi_round_fn(cfg: GlasuConfig,
                                 optimizer: opt_lib.Optimizer, mesh,
                                 axis: str = "clients",
-                                rounds_per_step: Optional[int] = None):
-    """K sharded rounds per dispatch: ``lax.scan`` INSIDE the shard_map, so
-    one collective program advances all K rounds — same donation,
-    carry-layout and round-stacked batch contract as
-    ``make_multi_round_fn`` (the (K, M) fault-mask stacks ride the scan
-    xs, replicated across devices)."""
+                                rounds_per_step: Optional[int] = None,
+                                record=None, jit: bool = True):
+    """K rounds with clients sharded over ``mesh``'s ``axis``:
+    ``lax.scan`` INSIDE the shard_map, so one collective program advances
+    all K rounds — the same step signature, donation, carry and
+    round-stacked batch contract as ``make_multi_round_fn`` (the (K, M)
+    fault-mask stacks ride the scan xs, replicated across devices).
+
+    ``record`` (see ``CollectiveRecord``) observes the aggregation
+    collectives at trace time, once per collective: the scan traces its
+    round body once. ``jit=False`` returns the bare step, which is what the
+    byte meter abstractly evaluates at bind on a one-round shell."""
     m_loc = _client_axis_check(cfg, mesh, axis)
-    pol = _policy(cfg, axis_name=axis, m_loc=m_loc)
-    has_c, has_f = _policy_arity(pol)
-    in_specs, out_specs = _round_specs(cfg, optimizer, pol, axis,
-                                       round_stacked=True)
-    step_fn = jax.jit(
-        jax.shard_map(_multi_round_caller(cfg, optimizer, pol), mesh=mesh,
-                      in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False),
-        donate_argnums=tuple(range(2 + has_c + has_f)))
+    pol = _policy(cfg, axis_name=axis, m_loc=m_loc, record=record)
+    in_specs, out_specs = _round_specs(cfg, optimizer, pol, axis)
+    step_fn = _step(jax.shard_map(_round_scan(cfg, optimizer, pol),
+                                  mesh=mesh, in_specs=in_specs,
+                                  out_specs=out_specs, check_vma=False))
+    if not jit:
+        return step_fn
+    step_fn = jax.jit(step_fn, donate_argnums=_DONATED)
     if rounds_per_step is None:
         return step_fn
     return _checked(step_fn, rounds_per_step, "sharded multi-round step")

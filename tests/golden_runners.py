@@ -98,13 +98,17 @@ def _masks(plans):
     return glasu.RoundFaults(jnp.asarray(present), jnp.asarray(weight))
 
 
-def _round_masks(plan):
-    import jax.numpy as jnp
+def _one_round(step, params, opt_state, batch, key, carry=None,
+               plan=None):
+    """One round through a builder's step at K = 1: the batch, the key and
+    the plan get a leading round axis of 1. Returns ``(params, opt_state,
+    losses (Q,), carry)``."""
+    from repro.graph.prefetch import stack_rounds
 
-    from repro.core import glasu
-
-    return glasu.RoundFaults(jnp.asarray(plan.present, jnp.float32),
-                             jnp.asarray(plan.weight, jnp.float32))
+    params, opt_state, losses, carry = step(
+        params, opt_state, stack_rounds([batch]), key[None], carry,
+        None if plan is None else _masks([plan]))
+    return params, opt_state, np.asarray(losses[0]), carry
 
 
 # --------------------------------------------------------------- combos
@@ -118,8 +122,8 @@ def vmapped_plain_multi():
     rounds, keys = _rounds_and_keys(sampler)
     step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=ROUNDS)
     import jax.numpy as jnp
-    params, opt_state, losses = step(params, opt_state, stack_rounds(rounds),
-                                     jnp.stack(keys))
+    params, opt_state, losses, _ = step(params, opt_state,
+                                        stack_rounds(rounds), jnp.stack(keys))
     return {**_flat("params", params), "losses": np.asarray(losses)}
 
 
@@ -129,11 +133,12 @@ def vmapped_privacy_round():
     mcfg, sampler = _base(secure_agg=True, dp_sigma=0.01)
     optimizer, params, opt_state = _init(mcfg)
     rounds, keys = _rounds_and_keys(sampler)
-    rf = glasu.make_round_fn(mcfg, optimizer)
+    step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=1)
     losses = []
     for t in range(ROUNDS):
-        params, opt_state, l = rf(params, opt_state, rounds[t], keys[t])
-        losses.append(np.asarray(l))
+        params, opt_state, l, _ = _one_round(step, params, opt_state,
+                                             rounds[t], keys[t])
+        losses.append(l)
     return {**_flat("params", params), "losses": np.stack(losses)}
 
 
@@ -143,11 +148,12 @@ def vmapped_concat_labels_round():
     mcfg, sampler = _base(agg="concat", labels_at_client=1)
     optimizer, params, opt_state = _init(mcfg)
     rounds, keys = _rounds_and_keys(sampler)
-    rf = glasu.make_round_fn(mcfg, optimizer)
+    step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=1)
     losses = []
     for t in range(ROUNDS):
-        params, opt_state, l = rf(params, opt_state, rounds[t], keys[t])
-        losses.append(np.asarray(l))
+        params, opt_state, l, _ = _one_round(step, params, opt_state,
+                                             rounds[t], keys[t])
+        losses.append(l)
     return {**_flat("params", params), "losses": np.stack(losses)}
 
 
@@ -161,12 +167,14 @@ def vmapped_int8_ef_round():
     comp = glasu.init_comp_state(mcfg, sampler.layer_sizes,
                                  compression.make_compressor(mcfg.compression))
     rounds, keys = _rounds_and_keys(sampler)
-    rf = glasu.make_round_fn(mcfg, optimizer)
+    step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=1)
+    carry = glasu.RoundCarry(comp_state=comp)
     losses = []
     for t in range(ROUNDS):
-        params, opt_state, comp, l = rf(params, opt_state, comp,
-                                        rounds[t], keys[t])
-        losses.append(np.asarray(l))
+        params, opt_state, l, carry = _one_round(step, params, opt_state,
+                                                 rounds[t], keys[t], carry)
+        losses.append(l)
+    comp = carry.comp_state
     return {**_flat("params", params), **_flat("comp", comp),
             "losses": np.stack(losses)}
 
@@ -185,9 +193,10 @@ def vmapped_topk_multi():
                                  compression.make_compressor(mcfg.compression))
     rounds, keys = _rounds_and_keys(sampler)
     step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=ROUNDS)
-    params, opt_state, comp, losses = step(params, opt_state, comp,
-                                           stack_rounds(rounds),
-                                           jnp.stack(keys))
+    params, opt_state, losses, carry = step(
+        params, opt_state, stack_rounds(rounds), jnp.stack(keys),
+        glasu.RoundCarry(comp_state=comp))
+    comp = carry.comp_state
     return {**_flat("params", params), **_flat("comp", comp),
             "losses": np.asarray(losses)}
 
@@ -203,10 +212,10 @@ def vmapped_fault_multi():
     cache = glasu.init_fault_state(mcfg, sampler.layer_sizes)
     rounds, keys = _rounds_and_keys(sampler)
     step = glasu.make_multi_round_fn(mcfg, optimizer, rounds_per_step=ROUNDS)
-    params, opt_state, cache, losses = step(params, opt_state, cache,
-                                            stack_rounds(rounds),
-                                            jnp.stack(keys),
-                                            _masks(_plans(mcfg)))
+    params, opt_state, losses, carry = step(
+        params, opt_state, stack_rounds(rounds), jnp.stack(keys),
+        glasu.RoundCarry(fault_state=cache), _masks(_plans(mcfg)))
+    cache = carry.fault_state
     return {**_flat("params", params), **_flat("cache", cache),
             "losses": np.asarray(losses)}
 
@@ -260,8 +269,8 @@ def sharded_plain_multi():
     rounds, keys = _rounds_and_keys(sampler)
     step = glasu.make_sharded_multi_round_fn(mcfg, optimizer, _sharded(mcfg),
                                              rounds_per_step=ROUNDS)
-    params, opt_state, losses = step(params, opt_state, stack_rounds(rounds),
-                                     jnp.stack(keys))
+    params, opt_state, losses, _ = step(params, opt_state,
+                                        stack_rounds(rounds), jnp.stack(keys))
     return {**_flat("params", params), "losses": np.asarray(losses)}
 
 
@@ -273,12 +282,15 @@ def sharded_fault_round():
     cache = glasu.init_fault_state(mcfg, sampler.layer_sizes)
     plans = _plans(mcfg)
     rounds, keys = _rounds_and_keys(sampler)
-    rf = glasu.make_sharded_round_fn(mcfg, optimizer, _sharded(mcfg))
+    step = glasu.make_sharded_multi_round_fn(mcfg, optimizer, _sharded(mcfg),
+                                             rounds_per_step=1)
+    carry = glasu.RoundCarry(fault_state=cache)
     losses = []
     for t in range(ROUNDS):
-        params, opt_state, cache, l = rf(params, opt_state, cache, rounds[t],
-                                         keys[t], _round_masks(plans[t]))
-        losses.append(np.asarray(l))
+        params, opt_state, l, carry = _one_round(
+            step, params, opt_state, rounds[t], keys[t], carry, plans[t])
+        losses.append(l)
+    cache = carry.fault_state
     return {**_flat("params", params), **_flat("cache", cache),
             "losses": np.stack(losses)}
 
@@ -293,12 +305,15 @@ def sharded_int8_ef_round():
     comp = glasu.init_comp_state(mcfg, sampler.layer_sizes,
                                  compression.make_compressor(mcfg.compression))
     rounds, keys = _rounds_and_keys(sampler)
-    rf = glasu.make_sharded_round_fn(mcfg, optimizer, _sharded(mcfg))
+    step = glasu.make_sharded_multi_round_fn(mcfg, optimizer, _sharded(mcfg),
+                                             rounds_per_step=1)
+    carry = glasu.RoundCarry(comp_state=comp)
     losses = []
     for t in range(ROUNDS):
-        params, opt_state, comp, l = rf(params, opt_state, comp, rounds[t],
-                                        keys[t])
-        losses.append(np.asarray(l))
+        params, opt_state, l, carry = _one_round(step, params, opt_state,
+                                                 rounds[t], keys[t], carry)
+        losses.append(l)
+    comp = carry.comp_state
     return {**_flat("params", params), **_flat("comp", comp),
             "losses": np.stack(losses)}
 

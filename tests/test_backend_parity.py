@@ -45,7 +45,8 @@ def test_round_parity_params_and_bytes():
     opt = cfg.make_optimizer()
     state_v = opt.init(params)
     state_s = opt.init(params)
-    pv, ps = params, params
+    # vmapped run_round consumes (donates) its params, as run_step does
+    pv, ps = jax.tree.map(jnp.array, params), params
     analytic = sampler.comm_bytes_per_joint_inference(mcfg.hidden, mcfg.agg)
     key = jax.random.PRNGKey(0)
     for t in range(2):

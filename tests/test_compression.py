@@ -227,18 +227,20 @@ def test_scan_matches_sequential_rounds(method, kw):
     keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
         jax.random.PRNGKey(1), jnp.arange(4))
 
-    rf = glasu.make_round_fn(mcfg, opt)
-    p1, s1, c1 = jax.tree.map(jnp.array, (params, opt.init(params), cs0))
+    mf = glasu.make_multi_round_fn(mcfg, opt)
+    p1, s1, c1 = jax.tree.map(jnp.array, (params, opt.init(params),
+                                          glasu.RoundCarry(cs0)))
     seq = []
     for t in range(4):
-        p1, s1, c1, l = rf(p1, s1, c1, rounds[t], keys[t])
-        seq.append(np.asarray(l))
+        p1, s1, l, c1 = mf(p1, s1, stack_rounds([rounds[t]]),
+                           keys[t:t + 1], c1)
+        seq.append(np.asarray(l[0]))
 
-    mf = glasu.make_multi_round_fn(mcfg, opt)
-    p2, s2, c2 = jax.tree.map(jnp.array, (params, opt.init(params), cs0))
-    p2, s2, c2, losses = mf(p2, s2, c2,
+    p2, s2, c2 = jax.tree.map(jnp.array, (params, opt.init(params),
+                                          glasu.RoundCarry(cs0)))
+    p2, s2, losses, c2 = mf(p2, s2,
                             jax.tree.map(jnp.asarray, stack_rounds(rounds)),
-                            keys)
+                            keys, c2)
     np.testing.assert_allclose(np.asarray(losses), np.stack(seq), **COMP_TOL)
     for (pa, la), (_, lb) in zip(jax.tree_util.tree_leaves_with_path(p1),
                                  jax.tree_util.tree_leaves_with_path(p2)):

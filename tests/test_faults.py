@@ -323,21 +323,24 @@ def test_composed_round_vmapped_matches_simulation_and_audits_bytes():
     comp = make_compressor(mcfg.compression)
     opt = cfg.make_optimizer()
     sched = make_schedule(cfg.faults, mcfg.n_clients)
-    round_fn = glasu.make_round_fn(mcfg, opt)
+    step_fn = glasu.make_multi_round_fn(mcfg, opt)
 
-    pv, ov = params, opt.init(params)
-    cs_v = glasu.init_comp_state(mcfg, sampler.layer_sizes, comp)
-    fs_v = glasu.init_fault_state(mcfg, sampler.layer_sizes)
+    # the step consumes (donates) its params, optimizer state and carry
+    pv, ov = jax.tree.map(jnp.array, params), opt.init(params)
+    carry_v = glasu.RoundCarry(
+        glasu.init_comp_state(mcfg, sampler.layer_sizes, comp),
+        glasu.init_fault_state(mcfg, sampler.layer_sizes))
     ps, os_ = params, opt.init(params)
-    cs_s, fs_s = cs_v, fs_v
+    cs_s, fs_s = jax.tree.map(jnp.array, tuple(carry_v))
     saw_partial = False
     for r in range(5):
         plan = sched.next_round()
         batch = jax.tree.map(jnp.asarray, sampler.sample_round())
-        masks = glasu.RoundFaults(jnp.asarray(plan.present),
-                                  jnp.asarray(plan.weight))
-        pv, ov, cs_v, fs_v, losses_v = round_fn(
-            pv, ov, cs_v, fs_v, batch, jax.random.PRNGKey(r), masks)
+        masks = glasu.RoundFaults(*map(jnp.asarray, stack_plans([plan])))
+        pv, ov, losses_v, carry_v = step_fn(
+            pv, ov, jax.tree.map(lambda x: x[None], batch),
+            jax.random.PRNGKey(r)[None], carry_v, masks)
+        losses_v = losses_v[0]
         (ps, os_, losses_s, log, fs_s,
          cs_s) = simulation.simulate_fault_round(
             ps, os_, batch, mcfg, opt, fs_s, plan,

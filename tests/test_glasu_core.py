@@ -27,6 +27,15 @@ def _setup(backbone="gcnii", agg="mean", agg_layers=(1, 3), m=3, q=1, seed=0):
     return data, mcfg, sampler, params, batch
 
 
+def _one_round(batch):
+    """``batch`` with a leading round axis of 1: the K = 1 step's input."""
+    return jax.tree.map(lambda x: x[None], batch)
+
+
+def _copy(tree):
+    return jax.tree.map(jnp.array, tree)
+
+
 def test_extract_consistency_mean():
     """Alg 3/4 core algebra: Agg(Extract(H, H_m+), H_m+) == H for every m.
 
@@ -100,9 +109,10 @@ def test_q_steps_update_params_q_times():
     _, cfg, sampler, params, batch = _setup(q=3)
     opt = opt_lib.sgd(0.1)
     state = opt.init(params)
-    round_fn = glasu.make_round_fn(cfg, opt)
-    p2, state, losses = round_fn(params, state, batch, jax.random.PRNGKey(0))
-    assert losses.shape == (3,)
+    step = glasu.make_multi_round_fn(cfg, opt)
+    p2, state, losses, _ = step(params, state, _one_round(batch),
+                                jax.random.PRNGKey(0)[None])
+    assert losses.shape == (1, 3)
     assert int(state.step) == 3
 
 
@@ -248,11 +258,12 @@ def test_pallas_backed_training_round_matches_jnp(backbone):
     _, cfg, _, params, batch = _setup(backbone=backbone)
     cfg_k = GlasuConfig(**{**cfg.__dict__, "use_pallas": True})
     opt = opt_lib.sgd(0.05)              # sgd: no adaptive noise amplification
-    state = opt.init(params)
-    p_j, _, loss_j = glasu.make_round_fn(cfg, opt)(
-        params, state, batch, jax.random.PRNGKey(0))
-    p_k, _, loss_k = glasu.make_round_fn(cfg_k, opt)(
-        params, state, batch, jax.random.PRNGKey(0))
+    batches, keys = _one_round(batch), jax.random.PRNGKey(0)[None]
+    # each step consumes (donates) its params and optimizer state
+    p_j, _, loss_j, _ = glasu.make_multi_round_fn(cfg, opt)(
+        _copy(params), opt.init(params), batches, keys)
+    p_k, _, loss_k, _ = glasu.make_multi_round_fn(cfg_k, opt)(
+        _copy(params), opt.init(params), batches, keys)
     np.testing.assert_allclose(np.asarray(loss_j), np.asarray(loss_k),
                                rtol=1e-5, atol=1e-5)
     for a, b in zip(jax.tree.leaves(p_j), jax.tree.leaves(p_k)):

@@ -431,26 +431,26 @@ def test_gl204_catches_meter_drift(monkeypatch):
     """Double every up_bytes the meter reports: the traced all_gather set no
     longer matches and the contract must fire."""
     glasu = contracts._fixture()["glasu"]
-    orig = glasu.make_sharded_round_fn
+    orig = glasu.make_sharded_multi_round_fn
 
-    def skewed(cfg, opt, mesh, axis="clients", record=None, jit=True):
+    def skewed(cfg, opt, mesh, record=None, **kw):
         wrapped = None if record is None else \
             (lambda r: record(r._replace(up_bytes=r.up_bytes * 2)))
-        return orig(cfg, opt, mesh, axis=axis, record=wrapped, jit=jit)
+        return orig(cfg, opt, mesh, record=wrapped, **kw)
 
-    monkeypatch.setattr(glasu, "make_sharded_round_fn", skewed)
+    monkeypatch.setattr(glasu, "make_sharded_multi_round_fn", skewed)
     got = contracts._check_collectives_vs_meter()
     assert any(f.rule == "GL204" and "drifted" in f.message for f in got)
 
 
 def test_gl204_catches_silent_meter(monkeypatch):
     glasu = contracts._fixture()["glasu"]
-    orig = glasu.make_sharded_round_fn
+    orig = glasu.make_sharded_multi_round_fn
 
-    def mute(cfg, opt, mesh, axis="clients", record=None, jit=True):
-        return orig(cfg, opt, mesh, axis=axis, record=None, jit=jit)
+    def mute(cfg, opt, mesh, record=None, **kw):
+        return orig(cfg, opt, mesh, record=None, **kw)
 
-    monkeypatch.setattr(glasu, "make_sharded_round_fn", mute)
+    monkeypatch.setattr(glasu, "make_sharded_multi_round_fn", mute)
     got = contracts._check_collectives_vs_meter()
     assert any(f.rule == "GL204" and "no collectives" in f.message
                for f in got)

@@ -5,6 +5,7 @@ identical* to K sequential rounds — same final params (bit-exact), same
 loss history, same comm-byte accounting — while compiling one round body
 and dispatching once per K rounds.
 """
+import dataclasses
 import json
 import time
 
@@ -16,13 +17,17 @@ import pytest
 from repro import obs
 from repro.api import ExperimentConfig, Trainer
 from repro.api.trainer import step_schedule
+from repro.comm.compression import CompressionConfig
 from repro.core import glasu
 from repro.core.glasu import GlasuConfig
+from repro.fed.faults import FaultConfig, FaultSchedule, stack_plans
 from repro.graph.prefetch import PrefetchSampler, stack_rounds, unstack_round
 from repro.graph.sampler import GlasuSampler, SamplerConfig
 from repro.graph.synth import make_vfl_dataset
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_client_mesh
 from repro.optim import optimizers as opt_lib
+from tools.glint.contracts import POLICY_COMBOS
 
 TINY = dict(name="engine", dataset="tiny", hidden=16, batch_size=8,
             size_cap=96, lr=0.02)
@@ -59,34 +64,94 @@ def _assert_trees_close(a, b, rtol=1e-5, atol=1e-6):
 
 
 # ----------------------------------------------------------- core scan fn
-def test_multi_round_fn_matches_sequential_rounds():
-    """One scanned K-round dispatch == K make_round_fn calls.
+def _with_policy(mcfg, policy: str):
+    """``mcfg`` under one of glint's execution-policy combinations."""
+    kw = {}
+    if "int8" in policy:
+        kw["compression"] = CompressionConfig(method="int8")
+    if "faults" in policy:
+        kw["fault_tolerant"] = True
+    return dataclasses.replace(mcfg, **kw)
 
-    Across the scan/non-scan compilation boundary XLA fuses differently, so
-    this is ULP-close rather than bit-equal; the engine's bit-exact
-    contract (rounds_per_step=K vs K steps of the engine at K=1) is covered
-    by the Trainer parity tests below."""
+
+def _build(transport: str, mcfg, opt):
+    """The transport's round builder; the sharded one on the client mesh
+    of the available devices (one on the tier-1 CPU)."""
+    if transport == "vmapped":
+        return glasu.make_multi_round_fn(mcfg, opt)
+    return glasu.make_sharded_multi_round_fn(
+        mcfg, opt, make_client_mesh(mcfg.n_clients))
+
+
+@pytest.mark.parametrize("transport", ["vmapped", "sharded"])
+@pytest.mark.parametrize("policy", POLICY_COMBOS)
+def test_multi_round_fn_matches_sequential_rounds(policy, transport):
+    """One scanned K = 4 dispatch == four K = 1 steps of the same builder,
+    the carries (error-feedback accumulators, fault cache) included, under
+    every execution policy and both transports.
+
+    XLA may fuse the K = 1 and K = 4 scans differently, so this is
+    ULP-close rather than bit-equal; the engine's bit-exact contract
+    (rounds_per_step=K vs K steps of the engine at K=1) is covered by the
+    Trainer parity tests below."""
     _, mcfg, sampler, params = _setup()
+    mcfg = _with_policy(mcfg, policy)
     opt = opt_lib.make_optimizer("adam", 0.02)
+    step_fn = _build(transport, mcfg, opt)
     rounds = [jax.tree.map(np.array, sampler.sample_round())
-              for _ in range(3)]
+              for _ in range(4)]
     key = jax.random.PRNGKey(7)
-    keys = jnp.stack([jax.random.fold_in(key, t) for t in range(3)])
+    keys = jnp.stack([jax.random.fold_in(key, t) for t in range(4)])
+    carry0 = glasu.RoundCarry(
+        glasu.init_comp_state(mcfg, sampler.layer_sizes),
+        glasu.init_fault_state(mcfg, sampler.layer_sizes))
+    faults = None
+    if mcfg.fault_tolerant:
+        sched = FaultSchedule(FaultConfig(
+            seed=5, drop_prob=0.3, deadline_ms=40.0, base_latency_ms=10.0,
+            straggler_prob=0.3, straggler_scale=6.0, max_staleness=2),
+            mcfg.n_clients)
+        present, weight = stack_plans([sched.next_round()
+                                       for _ in range(4)])
+        faults = glasu.RoundFaults(jnp.asarray(present), jnp.asarray(weight))
 
-    p_seq, s_seq = _copy(params), opt.init(params)
-    round_fn = glasu.make_round_fn(mcfg, opt)
+    p_seq, s_seq, c_seq = _copy((params, opt.init(params), carry0))
     seq_losses = []
-    for t in range(3):
-        p_seq, s_seq, losses = round_fn(p_seq, s_seq, rounds[t], keys[t])
-        seq_losses.append(losses)
+    for t in range(4):
+        p_seq, s_seq, losses, c_seq = step_fn(
+            p_seq, s_seq, stack_rounds([rounds[t]]), keys[t:t + 1], c_seq,
+            None if faults is None else jax.tree.map(lambda x: x[t:t + 1],
+                                                     faults))
+        seq_losses.append(losses[0])
 
-    step_fn = glasu.make_multi_round_fn(mcfg, opt)
-    p_k, s_k, losses_k = step_fn(_copy(params), opt.init(params),
-                                 stack_rounds(rounds), keys)
-    assert losses_k.shape == (3, mcfg.n_local_steps)
+    p_k, s_k, losses_k, c_k = step_fn(
+        *_copy((params, opt.init(params), stack_rounds(rounds))), keys,
+        _copy(carry0), faults)
+    assert losses_k.shape == (4, mcfg.n_local_steps)
+    assert jax.tree.structure(c_k) == jax.tree.structure(carry0)
     _assert_trees_close(p_k, p_seq)
     _assert_trees_close(s_k, s_seq)
+    _assert_trees_close(c_k, c_seq)
     _assert_trees_close(losses_k, jnp.stack(seq_losses))
+
+
+@pytest.mark.parametrize("transport", ["vmapped", "sharded"])
+def test_plain_step_lowers_without_carry_arguments(transport):
+    """Off the codec and fault axes the carry is ``RoundCarry(None, None)``,
+    an empty pytree: the step lowers to the same program whether the call
+    passes it or leaves it at its default, with one argument per leaf of
+    params, opt state, batches and keys."""
+    _, mcfg, sampler, params = _setup()
+    opt = opt_lib.make_optimizer("adam", 0.02)
+    step_fn = _build(transport, mcfg, opt)
+    args = (params, opt.init(params),
+            stack_rounds([sampler.sample_round() for _ in range(2)]),
+            jnp.stack([jax.random.PRNGKey(t) for t in range(2)]))
+    lowered = step_fn.lower(*args)
+    assert lowered.as_text() == step_fn.lower(
+        *args, glasu.RoundCarry(), None).as_text()
+    assert len(jax.tree.leaves(lowered.in_avals)) == \
+        len(jax.tree.leaves(args))
 
 
 def test_multi_round_fn_rejects_mismatched_k():
@@ -529,18 +594,19 @@ def test_round_fn_hot_path_is_transfer_free_and_trace_stable(
     everything else lives on device for the whole run."""
     _, mcfg, sampler, params = _setup()
     opt = opt_lib.make_optimizer("adam", 0.02)
-    round_fn = glasu.make_round_fn(mcfg, opt)
-    rounds = [jax.device_put(jax.tree.map(np.array, sampler.sample_round()))
+    step_fn = glasu.make_multi_round_fn(mcfg, opt)
+    rounds = [jax.device_put(stack_rounds([sampler.sample_round()]))
               for _ in range(4)]
-    # pre-staged per-round keys: eager `keys[t]` indexing inside the guard
-    # would upload its index scalar and (correctly) trip it
-    keys = [jax.random.fold_in(jax.random.PRNGKey(0), t) for t in range(4)]
+    # pre-staged one-round key stacks: eager `keys[t]` indexing inside the
+    # guard would upload its index scalar and (correctly) trip it
+    keys = [jax.random.fold_in(jax.random.PRNGKey(0), t)[None]
+            for t in range(4)]
     p, s = _copy(params), opt.init(_copy(params))
-    p, s, _ = round_fn(p, s, rounds[0], keys[0])      # the one compile
-    retrace_guard.watch(round_fn, "make_round_fn")
+    p, s, _, _ = step_fn(p, s, rounds[0], keys[0])    # the one compile
+    retrace_guard.watch(step_fn, "make_multi_round_fn")
     with transfer_guard():
         for t in range(1, 4):
-            p, s, _ = round_fn(p, s, rounds[t], keys[t])
+            p, s, _, _ = step_fn(p, s, rounds[t], keys[t])
     assert all(np.isfinite(np.asarray(x)).all() for x in jax.tree.leaves(p))
 
 
@@ -554,6 +620,6 @@ def test_multi_round_fn_k_change_is_the_only_retrace(retrace_guard):
               for _ in range(4)]
     keys = jnp.stack([jax.random.PRNGKey(t) for t in range(2)])
     p, s = _copy(params), opt.init(_copy(params))
-    p, s, _ = step_fn(p, s, stack_rounds(rounds[:2]), keys)
+    p, s, _, _ = step_fn(p, s, stack_rounds(rounds[:2]), keys)
     retrace_guard.watch(step_fn, "make_multi_round_fn")
-    p, s, _ = step_fn(p, s, stack_rounds(rounds[2:]), keys)
+    p, s, _, _ = step_fn(p, s, stack_rounds(rounds[2:]), keys)

@@ -17,15 +17,15 @@ see:
   GL204  the sharded round body's embedding collectives match the byte
          meter term by term: per-client wire bytes summed over ``all_gather``
          eqns equal the sum of ``CollectiveRecord.up_bytes`` (a drifted
-         meter is a static failure here, not a benchmark drift) — run
-         once per execution policy (plain / compressed / fault-tolerant /
-         composed)
+         meter is a static failure here, not a benchmark drift) — traced
+         through the sharded builder at K = 1, once per execution policy
+         (plain / compressed / fault-tolerant / composed)
 
 Entry points register in ``ENTRY_POINTS``; adding a public round/serve/
 kernel builder without registering it is itself a finding (GL200-style
 coverage is enforced in ``tests/test_glint.py``), and every execution
 policy of the unified round body (``core.glasu.ExecPolicy``) must ship a
-registered traceable entry for both multi-round builders
+registered traceable entry for both round builders
 (``_check_policy_coverage``).
 """
 from __future__ import annotations
@@ -180,7 +180,7 @@ def _collect_gathers(closed):
 
 # the ExecPolicy grid of the unified round body (core.glasu._round_body):
 # identity/int8 codec x all-present/fault-tolerant participation. Every
-# combination must ship a registered traceable entry for both multi-round
+# combination must ship a registered traceable entry for both round
 # builders — _check_policy_coverage fails the run otherwise.
 POLICY_COMBOS = ("plain", "int8", "faults", "int8+faults")
 
@@ -199,45 +199,40 @@ def _policy_cfg(policy: str):
     return cfg
 
 
-def _policy_args(cfg, k: int | None = None):
-    """Abstract call args of a (multi-)round builder under ``cfg``'s
-    policy: ``params, opt_state, [comp_state,] [fault_state,] batch(es),
-    key(s)[, faults]`` — the unified builder signature."""
+def _policy_args(cfg, k: int = 2):
+    """Abstract call args of a round builder's step under ``cfg``'s
+    policy: ``params, opt_state, batches, keys, carry, faults`` with a
+    leading round axis of ``k`` on the batches, keys and fault masks."""
     import jax
 
     fx = _fixture()
     glasu = fx["glasu"]
-    args = [fx["params"], fx["opt_state"]]
-    if cfg.compression is not None and cfg.compression.active:
-        args.append(jax.eval_shape(lambda: glasu.init_comp_state(
-            cfg, fx["sampler"].layer_sizes)))
+    layer_sizes = fx["sampler"].layer_sizes
+    carry = glasu.RoundCarry(
+        comp_state=jax.eval_shape(
+            lambda: glasu.init_comp_state(cfg, layer_sizes)),
+        fault_state=jax.eval_shape(
+            lambda: glasu.init_fault_state(cfg, layer_sizes)))
+    faults = None
     if cfg.fault_tolerant:
-        args.append(jax.eval_shape(lambda: glasu.init_fault_state(
-            cfg, fx["sampler"].layer_sizes)))
-    if k is None:
-        args += [fx["batch"], fx["key"]]
-    else:
-        args += [_stack_rounds(fx["batch"], k), _keys_abs(k)]
-    if cfg.fault_tolerant:
-        shape = (cfg.n_clients,) if k is None else (k, cfg.n_clients)
-        mask = jax.ShapeDtypeStruct(shape, "float32")
-        args.append(glasu.RoundFaults(mask, mask))
-    return tuple(args)
+        mask = jax.ShapeDtypeStruct((k, cfg.n_clients), "float32")
+        faults = glasu.RoundFaults(mask, mask)
+    return (fx["params"], fx["opt_state"], _stack_rounds(fx["batch"], k),
+            _keys_abs(k), carry, faults)
 
 
-def _n_donated_leaves(cfg, args) -> int:
-    """params + opt_state + every active carry (the donate_argnums set of
-    the unified multi-round builders)."""
+def _n_donated_leaves(args) -> int:
+    """params + opt_state + the carry's active members (the donate_argnums
+    set of both round builders)."""
     import jax
-    n_carries = 2 + int(cfg.compression is not None
-                        and cfg.compression.active) + int(cfg.fault_tolerant)
-    return len(jax.tree.leaves(args[:n_carries]))
+    params, opt_state, _, _, carry, _ = args
+    return len(jax.tree.leaves((params, opt_state, carry)))
 
 
 def _check_collectives_vs_meter(policy: str = "plain") -> List[Finding]:
-    """GL204: trace the sharded round body under one execution policy,
-    compare its all_gather set against the CollectiveRecords the byte
-    meter emits for the same trace."""
+    """GL204: trace the sharded builder's step at K = 1 under one execution
+    policy, compare its all_gather set against the CollectiveRecords the
+    byte meter emits for the same trace."""
     import jax
     from repro.launch.mesh import make_client_mesh
 
@@ -247,13 +242,13 @@ def _check_collectives_vs_meter(policy: str = "plain") -> List[Finding]:
     where = "src/repro/core/glasu.py"
     mesh = make_client_mesh(cfg.n_clients)
     records = []
-    fn = glasu.make_sharded_round_fn(cfg, fx["opt"], mesh,
-                                     record=records.append, jit=False)
-    args = _policy_args(cfg)
+    fn = glasu.make_sharded_multi_round_fn(cfg, fx["opt"], mesh,
+                                           record=records.append, jit=False)
+    args = _policy_args(cfg, k=1)
     with mesh:
         closed = jax.make_jaxpr(fn)(*args)
 
-    name = f"make_sharded_round_fn[{policy}]"
+    name = f"make_sharded_multi_round_fn[{policy}]"
     out = []
     if not records:
         return [Finding("GL204", where, 1,
@@ -279,37 +274,14 @@ def _check_collectives_vs_meter(policy: str = "plain") -> List[Finding]:
 
 
 # ------------------------------------------------------------- entry points
-def _ep_round_fn():
-    import jax
-    fx = _fixture()
-    fn = fx["glasu"].make_round_fn(fx["cfg"], fx["opt"])
-    closed = jax.make_jaxpr(fn)(fx["params"], fx["opt_state"], fx["batch"],
-                                fx["key"])
-    return closed, None
-
-
 def _ep_multi_round_fn(policy: str = "plain"):
     import jax
     fx = _fixture()
     cfg = _policy_cfg(policy)
-    k = 2
     fn = fx["glasu"].make_multi_round_fn(cfg, fx["opt"])
-    args = _policy_args(cfg, k=k)
+    args = _policy_args(cfg)
     closed = jax.make_jaxpr(fn)(*args)
-    return closed, (fn, args, _n_donated_leaves(cfg, args))
-
-
-def _ep_sharded_round_fn():
-    import jax
-    from repro.launch.mesh import make_client_mesh
-    fx = _fixture()
-    mesh = make_client_mesh(fx["cfg"].n_clients)
-    fn = fx["glasu"].make_sharded_round_fn(fx["cfg"], fx["opt"], mesh,
-                                           jit=False)
-    with mesh:
-        closed = jax.make_jaxpr(fn)(fx["params"], fx["opt_state"],
-                                    fx["batch"], fx["key"])
-    return closed, None
+    return closed, (fn, args, _n_donated_leaves(args))
 
 
 def _ep_sharded_multi_round_fn(policy: str = "plain"):
@@ -317,15 +289,14 @@ def _ep_sharded_multi_round_fn(policy: str = "plain"):
     from repro.launch.mesh import make_client_mesh
     fx = _fixture()
     cfg = _policy_cfg(policy)
-    k = 2
     mesh = make_client_mesh(cfg.n_clients)
     fn = fx["glasu"].make_sharded_multi_round_fn(cfg, fx["opt"], mesh)
-    args = _policy_args(cfg, k=k)
+    args = _policy_args(cfg)
     with mesh:
         closed = jax.make_jaxpr(fn)(*args)
         findings = _check_donation(
             f"make_sharded_multi_round_fn[{policy}]", fn, args,
-            _n_donated_leaves(cfg, args), "src/repro/core/glasu.py")
+            _n_donated_leaves(args), "src/repro/core/glasu.py")
     return closed, ("inline", findings)
 
 
@@ -476,10 +447,7 @@ def _ep_resident_masked_mean_kernel():
 
 # name -> (builder, repo-relative path of the code under contract)
 ENTRY_POINTS: Dict[str, Tuple[Callable, str]] = {
-    "make_round_fn": (_ep_round_fn, "src/repro/core/glasu.py"),
     "make_multi_round_fn": (_ep_multi_round_fn, "src/repro/core/glasu.py"),
-    "make_sharded_round_fn": (_ep_sharded_round_fn,
-                              "src/repro/core/glasu.py"),
     "make_sharded_multi_round_fn": (_ep_sharded_multi_round_fn,
                                     "src/repro/core/glasu.py"),
     # non-plain ExecPolicy combinations of the unified round body: same
@@ -512,7 +480,7 @@ ENTRY_POINTS: Dict[str, Tuple[Callable, str]] = {
 
 def _check_policy_coverage() -> List[Finding]:
     """Every ExecPolicy combination of the unified round body must ship a
-    registered traceable entry for both multi-round builders — the jit
+    registered traceable entry for both round builders — the jit
     boundaries the Trainer actually dispatches. A policy added to
     ``POLICY_COMBOS`` without its entries is a finding, not a silent gap
     in contract coverage."""
